@@ -8,8 +8,8 @@ Times the solver impls per chain length:
 - **banded-noprune** — the same fill with ``REPRO_DP_PRUNE=0`` (the pruning
   delta is recorded as ``pruning_speedup`` on this row),
 - **pallas**         — the per-band Pallas kernel (``repro.kernels.dp_fill``)
-  behind ``impl="pallas"``; on this CPU host it runs in interpret mode (the
-  TPU dispatch seam's fallback), so it is timed only up to
+  behind ``impl="pallas"``; these are CPU rows, so ``run()`` runs it in
+  Pallas interpret mode (and restores the setting) and times it only up to
   ``pallas_max_len`` — the row records the *seam*, not TPU speed,
 - **pallas_fused**   — the device-resident fill behind ``impl="pallas_fused"``:
   the whole band recursion in ONE ``pallas_call`` (no per-band host loop) —
@@ -42,13 +42,14 @@ import numpy as np
 from repro.core.chain import Chain, HostTransferModel
 from repro.core.schedule import Schedule, simulate
 from repro.core.solver import solve_optimal
+from repro.kernels.dp_fill.ops import interpreting
 from repro.offload.solver import solve_optimal_offload
 
 JSON_PATH = "BENCH_solver.json"
 
 #: Interpret-mode Pallas executes kernel bodies in Python — fine for parity,
 #: hopeless for timing big chains on CPU.  Lengths above this are skipped
-#: (and logged) unless a TPU backend is present.
+#: (and logged).
 PALLAS_MAX_LEN = 50
 
 
@@ -103,6 +104,9 @@ def _best_of(fn, repeats: int):
     return best, out
 
 
+# these are CPU rows: the Pallas fills interpret their kernels, and the
+# previous setting comes back when run() returns
+@interpreting()
 def run(lengths=(20, 50, 100, 200, 339), num_slots=500, emit=print,
         reference=True, offload=True, repeats=2, pallas=True,
         pallas_max_len=PALLAS_MAX_LEN, prune_rows=True):
@@ -179,7 +183,7 @@ def run(lengths=(20, 50, 100, 200, 339), num_slots=500, emit=print,
                     assert sol_f.expected_time == sol_b.expected_time
             else:
                 emit(f"# pallas/pallas_fused: skipped at L={L} "
-                     f"(interpret-mode CPU fallback; rows capped at "
+                     f"(interpret mode; rows capped at "
                      f"L<={pallas_max_len})")
         if reference:
             dt_r, sol_r = _best_of(

@@ -238,7 +238,8 @@ class _Interp:
             return buf.values[i]
         if isinstance(index, tuple) and len(index) == 2:
             a, b = index
-            if isinstance(a, DS) and isinstance(b, DS):  # (L, rt·BR) mats
+            # ref[pl.ds(row, 1), pl.ds(col, BR)] over the (L, rt·BR) mats
+            if isinstance(a, DS) and isinstance(b, DS):
                 lo0, hi0 = self._slice_1d(buf, a, f"read {buf.name}")
                 if b.start < 0 or b.start + b.size > buf.shape[1]:
                     self.issue(
@@ -447,11 +448,6 @@ class _Interp:
             if not all(isinstance(a, (int, bool)) for a in args):
                 raise _Unsupported("pl.ds with non-concrete bounds")
             return DS(args[0], args[1])
-        if dotted == "pl.load":
-            buf = args[0]
-            if not isinstance(buf, Buf):
-                raise _Unsupported("pl.load of a non-ref")
-            return self.read_buf(buf, args[1])
         if dotted == "jax.lax.fori_loop":
             lo, hi, fn, carry = args
             if not (

@@ -669,7 +669,7 @@ def fill_tables(dchain, S: int, impl: str = "banded",
     """Two-tier band fill behind the ``impl`` seam: ``"banded"`` runs this
     module's numpy kernels; ``"pallas"`` dispatches (lazily, so the numpy
     core never imports jax) to :mod:`repro.kernels.dp_fill` — the per-band
-    Pallas kernel, jit on TPU and interpret-mode on CPU; ``"pallas_fused"``
+    Pallas kernel, compiled for a TPU; ``"pallas_fused"``
     runs the same package's device-resident fill (one ``pallas_call`` for
     the whole recursion).  All produce the same :class:`BandedTable` layout,
     so reconstruction is impl-agnostic.  (``"reference"`` keeps its own

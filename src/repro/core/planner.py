@@ -4,10 +4,11 @@ sequence of JAX stage functions.
 Two modes, mirroring the two ways we run:
 
 - **analytic** (dry-run / TPU-target): per-stage FLOPs from
-  ``jit(fn).lower(...).compile().cost_analysis()`` divided by a peak FLOP/s
-  constant; activation/residual *sizes* are exact, from ``jax.eval_shape`` of
-  the stage and of its VJP (the VJP closure is a pytree whose leaves are the
-  residual tensors — JAX's ``ā^l``).  Residual leaves that are shape/dtype-
+  ``jit(fn).lower(...).compile().cost_analysis()`` divided by the planned
+  device's peak FLOP/s (:mod:`repro.core.devices`); activation/residual
+  *sizes* are exact, from ``jax.eval_shape`` of the stage and of its VJP
+  (the VJP closure is a pytree whose leaves are the residual tensors —
+  JAX's ``ā^l``).  Residual leaves that are shape/dtype-
   identical to parameter leaves are greedily excluded (the paper removes
   model/grad memory from the activation budget, §3.1).
 - **measured** (CPU reproduction benchmarks): wall-clock each stage's forward
@@ -28,9 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .chain import Chain, HostTransferModel
-
-# TPU v5e-ish defaults; overridable.
-PEAK_FLOPS_BF16 = 197e12
+from .devices import device_peaks
 
 
 def measure_host_bandwidth(sample_bytes: int = 1 << 26, repeats: int = 3,
@@ -85,8 +84,7 @@ def residual_bytes(fn: Callable, p: Any, a: Any) -> int:
 
 
 def _flops_of(fn: Callable, *args) -> float:
-    from ..compat import cost_analysis_dict
-    ca = cost_analysis_dict(jax.jit(fn).lower(*args).compile())
+    ca = jax.jit(fn).lower(*args).compile().cost_analysis() or {}
     return float(ca.get("flops", 0.0))
 
 
@@ -94,7 +92,7 @@ def profile_stages_analytic(
     stages: Sequence[Callable],
     params: Sequence[Any],
     x: Any,
-    peak_flops: float = PEAK_FLOPS_BF16,
+    peak_flops: Optional[float] = None,
     activation_shard_factor: float = 1.0,
     flops_fwd: Optional[Sequence[float]] = None,
     flops_bwd: Optional[Sequence[float]] = None,
@@ -106,7 +104,11 @@ def profile_stages_analytic(
     the product of mesh-axis sizes over which activations are sharded so the
     DP sees *per-device* bytes.  ``flops_fwd/bwd`` skip the per-stage compiles
     when the caller already knows the FLOP counts (e.g. from config math).
+    ``peak_flops`` defaults to the bf16 peak of the device JAX runs on
+    (:mod:`repro.core.devices`).
     """
+    if peak_flops is None:
+        peak_flops = device_peaks(jax.devices()[0]).flops_bf16
     n = len(stages)
     uf, ub, wa, wabar = [], [], [], []
     wa.append(_pytree_bytes(jax.eval_shape(lambda v: v, x)) / activation_shard_factor)

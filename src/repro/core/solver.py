@@ -18,7 +18,7 @@ Four fill implementations share the recursion (``dp_kernels.KNOWN_IMPLS``):
   published makespan is exact.
 - ``impl="pallas"``: the same band recursion with the split-batched min
   reduction on the per-band Pallas kernel of :mod:`repro.kernels.dp_fill` —
-  jit on TPU, interpret-mode CPU fallback elsewhere; band-exact against
+  compiled for a TPU (interpret mode only when asked for); band-exact against
   ``"banded"`` (tested on f32-exact chains).  The band loop stays on the
   host: O(L) kernel dispatches per fill.
 - ``impl="pallas_fused"``: the whole band recursion in ONE ``pallas_call``

@@ -110,10 +110,8 @@ def measure(
     dchain, Sc = _calibration_chain(L, S, interpret)
     Lc = dchain.length
     effective = sorted({min(int(c), Lc) for c in candidates})
-    previous = ops._INTERPRET[0]
-    ops.set_interpret(interpret)
     timings = {}
-    try:
+    with ops.interpreting(interpret):
         for br in effective:
             best = None
             for _ in range(max(1, repeats)):
@@ -122,8 +120,6 @@ def measure(
                 dt = time.perf_counter() - t0
                 best = dt if best is None else min(best, dt)
             timings[int(br)] = best
-    finally:
-        ops.set_interpret(previous)
     winner = min(timings, key=timings.get)
     return {"version": _VERSION, "block_rows": int(winner), "timings": timings}
 

@@ -268,7 +268,7 @@ def _fused_two_tier_kernel(
             return jnp.minimum(acc, cand)
 
         acc = jax.lax.fori_loop(0, d, split, jnp.full((BR, W), inf, COST_DT))
-        mn = pl.load(mn_ref, (pl.ds(d - 1, 1), pl.ds(r0, BR)))[0][:, None]
+        mn = mn_ref[pl.ds(d - 1, 1), pl.ds(r0, BR)][0][:, None]
         res = jnp.where(cols < mn, inf, acc)
         if allow_fall:
             # C2: u_f^s + C[s+1, t][m - wabar^s] + u_b^s, masked by m_all
@@ -277,7 +277,7 @@ def _fused_two_tier_kernel(
             uf = uf_ref[pl.ds(1 + r0, BR)][:, None]
             ub = ub_ref[pl.ds(1 + r0, BR)][:, None]
             c2 = (_shifted_gather(blk, idx, W) + uf) + ub
-            ma = pl.load(ma_ref, (pl.ds(d - 1, 1), pl.ds(r0, BR)))[0][:, None]
+            ma = ma_ref[pl.ds(d - 1, 1), pl.ds(r0, BR)][0][:, None]
             res = jnp.minimum(res, jnp.where(cols < ma, inf, c2))
         t_ref[pl.ds(off_ref[d] + r0, BR), :] = res
 
@@ -415,7 +415,7 @@ def _fused_offload_kernel(
         accb, acce, acc3 = jax.lax.fori_loop(
             0, d, split, (start_acc, start_acc, start_acc)
         )
-        mn = pl.load(mn_ref, (pl.ds(d - 1, 1), pl.ds(r0, BR)))[0][:, None]
+        mn = mn_ref[pl.ds(d - 1, 1), pl.ds(r0, BR)][0][:, None]
         infeas = cols < mn
         resb = jnp.where(infeas, inf, accb)
         rese = jnp.where(infeas, inf, acce)
@@ -426,7 +426,7 @@ def _fused_offload_kernel(
             uf = uf_ref[pl.ds(1 + r0, BR)][:, None]
             ub = ub_ref[pl.ds(1 + r0, BR)][:, None]
             c2 = (_shifted_gather(blk, idx, W) + uf) + ub
-            ma = pl.load(ma_ref, (pl.ds(d - 1, 1), pl.ds(r0, BR)))[0][:, None]
+            ma = ma_ref[pl.ds(d - 1, 1), pl.ds(r0, BR)][0][:, None]
             c2 = jnp.where(cols < ma, inf, c2)
             resb = jnp.minimum(resb, c2)
             rese = jnp.minimum(rese, c2)

@@ -18,15 +18,15 @@ same C2 fall plane) but hand the DP's hot loop to the Pallas kernels in
   after the fact.  ``block_rows`` (the row-tile height) resolves through
   :mod:`.autotune` when not given.
 
-Dispatch seam: on a TPU backend the kernels run jitted; everywhere else they
-fall back to Pallas interpret mode automatically, so both impls are runnable
-(slowly) in CPU CI — that is what the parity suite
-``tests/test_dp_fill_pallas.py`` exercises.  ``set_interpret`` overrides the
-automatic choice, matching the other kernel packages.
+Dispatch seam: the kernels run compiled, which needs a TPU backend.
+Interpret mode (kernel bodies executed in Python, on any backend) runs only
+when :func:`set_interpret` asks for it — the CPU tests and the CPU bench rows
+do; a compiled dispatch off a TPU raises instead of quietly interpreting.
 """
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
 import jax
@@ -46,20 +46,40 @@ from ...core.dp_kernels import (
 )
 from . import kernel
 
-_INTERPRET: list = [None]
+_INTERPRET: list = [False]
 
 
-def set_interpret(flag: Optional[bool]) -> None:
-    """``True`` forces interpret mode, ``False`` forces compiled dispatch,
-    ``None`` restores the automatic choice (compiled on TPU, interpret
-    elsewhere)."""
-    _INTERPRET[0] = flag if flag is None else bool(flag)
+def set_interpret(flag: bool) -> None:
+    """``True`` runs the kernels in Pallas interpret mode; ``False`` (the
+    default) dispatches them compiled."""
+    _INTERPRET[0] = bool(flag)
+
+
+@contextlib.contextmanager
+def interpreting(flag: bool = True):
+    """:func:`set_interpret` for the enclosed fills only; the previous
+    setting is restored on exit."""
+    previous = _INTERPRET[0]
+    set_interpret(flag)
+    try:
+        yield
+    finally:
+        set_interpret(previous)
 
 
 def interpret_mode() -> bool:
-    if _INTERPRET[0] is not None:
-        return _INTERPRET[0]
-    return jax.default_backend() != "tpu"
+    """Whether the fills interpret the kernels.  Raises when compiled
+    dispatch is asked for on a backend that is not a TPU."""
+    if _INTERPRET[0]:
+        return True
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"the Pallas DP fills (impl='pallas' / 'pallas_fused') compile "
+            f"for a TPU, but JAX's backend is {backend!r}; call "
+            f"repro.kernels.dp_fill.ops.set_interpret(True) to run them in "
+            f"interpret mode")
+    return False
 
 
 def fill_two_tier(dchain, S: int, allow_fall: bool = True,

@@ -19,34 +19,41 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_chunk_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_ref):
-    # blocks: x (1,1,Q,P), dt (1,1,Q), a (1,), b/c (1,Q,N) [per-group, shared
-    # across the heads mapped to it], outputs y (1,1,Q,P), s (1,1,P,N)
+    # blocks: x (1,1,Q,P), dt (1,1,1,Q) [a lane row], b/c (1,1,Q,N)
+    # [heads pre-broadcast to groups], outputs y (1,1,Q,P), s (1,1,P,N);
+    # a is the whole (BH,) vector in SMEM, read as this head's scalar
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
-    a = a_ref[0].astype(jnp.float32)             # scalar
+    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, Q)
+    a = a_ref[pl.program_id(0)]                  # scalar
     bm = b_ref[0, 0].astype(jnp.float32)         # (Q, N)
     cm = c_ref[0, 0].astype(jnp.float32)         # (Q, N)
 
-    da = dt * a                                  # (Q,)
-    cs = jnp.cumsum(da)                          # within-chunk cumsum
+    da = dt * a                                  # (1, Q)
     Q = x.shape[0]
-    # L[i, j] = exp(cs_i - cs_j) for j <= i else 0
     li = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     lj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(lj <= li, jnp.exp(cs[:, None] - cs[None, :]), 0.0)
+    # within-chunk cumsum as a matmul with the upper-triangular ones (Mosaic
+    # has no cumsum); HIGHEST keeps the operands in f32, not bf16
+    cs = jax.lax.dot_general(da, (li <= lj).astype(jnp.float32),
+                             (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)  # (1, Q)
+    # L[i, j] = exp(cs_i - cs_j) for j <= i else 0
+    L = jnp.where(lj <= li, jnp.exp(jnp.transpose(cs) - cs), 0.0)
 
     scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q,Q)
-    w = scores * L * dt[None, :]
+    w = scores * L * dt
     y_ref[0, 0] = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    decay = jnp.exp(cs[-1] - cs)                 # (Q,)
-    bw = bm * (decay * dt)[:, None]              # (Q, N)
+    decay = jnp.exp(cs[:, Q - 1:] - cs)          # (1, Q)
+    bw = bm * jnp.transpose(decay * dt)          # (Q, N)
     s_ref[0, 0] = jax.lax.dot_general(
         x, bw, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32).astype(s_ref.dtype)  # (P, N)
@@ -66,8 +73,8 @@ def ssd_chunk_blocks(x: jax.Array, dt: jax.Array, A: jax.Array,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, 1, Q, P), lambda b, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1,), lambda b, c: (b,)),
+            pl.BlockSpec((1, 1, 1, Q), lambda b, c: (b, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, Q, N), lambda b, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, c: (b, c, 0, 0)),
         ],
@@ -80,4 +87,4 @@ def ssd_chunk_blocks(x: jax.Array, dt: jax.Array, A: jax.Array,
             jax.ShapeDtypeStruct((BH, nc, P, N), jnp.float32),
         ],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+    )(x, dt.reshape(BH, nc, 1, Q), A.astype(jnp.float32), Bm, Cm)

@@ -29,7 +29,7 @@ import numpy as np
 
 from ..core.schedule import BWD, F_ALL, F_CK, F_NONE, Schedule
 from ..models.flops import _layer_flops, stage_flops
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from ..core.devices import V5E
 
 
 def _bytes_of_tree(tree) -> int:
@@ -64,7 +64,7 @@ def train_terms(cfg, shape, mesh, model, chain, schedule: Optional[Schedule]
         total_flops += c * fwd_flops[l - 1]
         # backward = 2×fwd (+1×fwd replay if inner per-layer remat)
         total_flops += (2.0 + inner) * fwd_flops[l - 1]
-    compute_s = total_flops / n_dev / PEAK_FLOPS_BF16
+    compute_s = total_flops / n_dev / V5E.flops_bf16
 
     # --- memory traffic (per device) --------------------------------------
     params_spec = jax.eval_shape(model.init, jax.random.PRNGKey(0))
@@ -83,7 +83,7 @@ def train_terms(cfg, shape, mesh, model, chain, schedule: Optional[Schedule]
     # optimizer: p(read+write) bf16 + m,v f32 (read+write), grads read — all
     # fully sharded (ZeRO-3): 2·2 + 2·8 + 2 = 22 bytes/param ÷ n_dev
     traffic += 22.0 * (p_total / 2) / n_dev
-    memory_s = traffic / HBM_BW
+    memory_s = traffic / V5E.hbm_bw
 
     # --- collectives (per device) ------------------------------------------
     coll = 0.0
@@ -103,7 +103,7 @@ def train_terms(cfg, shape, mesh, model, chain, schedule: Optional[Schedule]
         buf = cfg.num_experts * cap * cfg.d_model * 2  # bf16
         passes = 2 + 2 + (2 if cfg.scan_layer_remat == "full" else 0)
         coll += n_moe * buf * passes * (tp - 1) / tp
-    collective_s = coll / ICI_BW
+    collective_s = coll / V5E.ici_bw
     return {"compute_s": compute_s, "memory_s": memory_s,
             "collective_s": collective_s,
             "flops_per_device": total_flops / n_dev,
@@ -128,17 +128,17 @@ def decode_terms(cfg, shape, mesh, model) -> Dict[str, float]:
         if kind in ("dense", "moe"):
             flops += length * (_layer_flops(cfg, "dense", B, 1, kv_len=S)
                                - _layer_flops(cfg, "dense", B, 1, kv_len=1))
-    compute_s = flops / n_dev / PEAK_FLOPS_BF16
+    compute_s = flops / n_dev / V5E.flops_bf16
     # memory: read the resident param shard + the whole cache; the cache
     # write-back is only the new token's slice (the cache buffer is donated
     # and aliased in place on TPU)
     traffic = p_bytes / n_dev + c_bytes / n_dev * (1.0 + 1.0 / max(S, 1))
-    memory_s = traffic / HBM_BW
+    memory_s = traffic / V5E.hbm_bw
     # collectives: per-layer activation all-reduce for TP (y partial sums)
     n_layers = cfg.num_layers
     coll = n_layers * B / dp * cfg.d_model * 2 * 2 * (tp - 1) / tp
     return {"compute_s": compute_s, "memory_s": memory_s,
-            "collective_s": coll / ICI_BW,
+            "collective_s": coll / V5E.ici_bw,
             "flops_per_device": flops / n_dev,
             "hbm_bytes_per_device": traffic,
             "collective_bytes_per_device": coll}
@@ -157,9 +157,9 @@ def prefill_terms(cfg, shape, mesh, model) -> Dict[str, float]:
     traffic = (p_bytes + act + c_bytes) / n_dev
     tp = _axis(mesh, "model")
     coll = (p_bytes / n_dev) * (mesh.size / tp - 1)  # FSDP gathers
-    return {"compute_s": flops / n_dev / PEAK_FLOPS_BF16,
-            "memory_s": traffic / HBM_BW,
-            "collective_s": coll / ICI_BW,
+    return {"compute_s": flops / n_dev / V5E.flops_bf16,
+            "memory_s": traffic / V5E.hbm_bw,
+            "collective_s": coll / V5E.ici_bw,
             "flops_per_device": flops / n_dev,
             "hbm_bytes_per_device": traffic,
             "collective_bytes_per_device": coll}

@@ -139,7 +139,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, name), "w") as f:
         json.dump(rec, f, indent=1)
-    hbm = 16 * 1024**3
+    from ..core.devices import V5E
+    hbm = V5E.hbm_bytes
     print(f"[dryrun] {arch} × {shape_name} × {rec['mesh']}: "
           f"peak={rec['memory']['peak_bytes']/2**30:.2f} GiB/dev "
           f"({'FITS' if rec['memory']['peak_bytes'] <= hbm else 'OVER'} 16GiB) "

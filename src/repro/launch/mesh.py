@@ -1,30 +1,31 @@
-"""Production mesh construction.
+"""Mesh construction — the one place a ``jax.sharding.Mesh`` is built.
 
-A FUNCTION (not a module-level constant) so importing this module never
-touches jax device state — the dry-run sets
+Functions, not module-level constants, so importing this module never
+touches jax device state (the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
-init; tests and benches must keep seeing 1 device.
+init; tests and benches must keep seeing 1 device).
 """
 
 from __future__ import annotations
 
+from typing import Optional, Sequence
+
 import jax
+from jax.sharding import AxisType, Mesh
 
 
-def make_production_mesh(*, multi_pod: bool = False):
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """A mesh whose axes are all ``Auto``.  The logical-axis rules
+    (:mod:`repro.distributed.sharding`) constrain activations with GSPMD
+    ``with_sharding_constraint``, which only accepts ``Auto`` axes;
+    ``jax.make_mesh`` now defaults to ``Explicit`` ones."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(model_parallel: int = 1, axes=("data", "model")):
-    """Whatever this host offers (1 device on CPU; 8 under the test flag)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n // model_parallel, model_parallel), axes)
-
-
-# TPU v5e hardware constants used by the roofline (§Roofline).
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
-HBM_BYTES = 16 * 1024 ** 3    # 16 GiB per chip
+    return make_mesh(shape, axes)

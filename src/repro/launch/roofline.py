@@ -16,7 +16,7 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from ..core.devices import V5E
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -105,15 +105,14 @@ class Roofline:
 
 def analyze(compiled, n_devices: int, model_flops: float,
             hlo_text: Optional[str] = None) -> Roofline:
-    from ..compat import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_bytes_from_hlo(text)
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = byts / HBM_BW
-    collective_s = coll["total"] / ICI_BW
+    compute_s = flops / V5E.flops_bf16
+    memory_s = byts / V5E.hbm_bw
+    collective_s = coll["total"] / V5E.ici_bw
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)

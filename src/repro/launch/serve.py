@@ -12,6 +12,7 @@ import numpy as np
 from ..configs import get_config, smoke_config
 from ..models.lm import StagedLM
 from ..runtime.serve_loop import ServeLoopConfig, run_serving
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -23,6 +24,7 @@ def main(argv=None) -> int:
     ap.add_argument("--max-new-tokens", type=int, default=16)
     ap.add_argument("--override", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ov = json.loads(args.override) if args.override else {}
     cfg = smoke_config(args.arch, **ov) if args.smoke else get_config(args.arch, **ov)

@@ -13,6 +13,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import solver_cache
 from ..core.chain import Chain
+from ..core.devices import device_peaks, hbm_bytes
 from ..core.policies import resolve_policy
 from ..plan import MemoryPlan, two_tier_fallback
 from ..distributed.sharding import (DEFAULT_RULES, LONG_CONTEXT_RULES,
@@ -20,7 +21,6 @@ from ..distributed.sharding import (DEFAULT_RULES, LONG_CONTEXT_RULES,
 from ..models.flops import stage_flops
 from ..models.lm import StagedLM
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
-from .mesh import HBM_BYTES, PEAK_FLOPS_BF16
 
 
 # ---------------------------------------------------------------------------
@@ -81,10 +81,12 @@ def opt_axes(param_axes: Any) -> Dict[str, Any]:
 # rotor planning at scale
 # ---------------------------------------------------------------------------
 
-def activation_budget_bytes(params_spec: Any, n_devices: int,
-                            hbm: int = HBM_BYTES, slack: float = 0.9) -> float:
+def activation_budget_bytes(params_spec: Any, n_devices: int, hbm: int,
+                            slack: float = 0.9) -> float:
     """Per-device activation budget = HBM − (params + grads + Adam moments),
-    assuming full (FSDP×TP) sharding of all three (ZeRO-3 via GSPMD)."""
+    assuming full (FSDP×TP) sharding of all three (ZeRO-3 via GSPMD).
+    ``hbm`` is the planned device's memory (:func:`repro.core.devices
+    .hbm_bytes`)."""
     p_bytes = sum(int(math.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
                   for l in jax.tree.leaves(params_spec))
     per_dev_states = p_bytes * (1 + 1 + 4) / n_devices  # bf16 p+g, f32 m+v
@@ -93,7 +95,8 @@ def activation_budget_bytes(params_spec: Any, n_devices: int,
 
 def plan_chain(model: StagedLM, batch_specs: Dict, mesh, rules) -> Chain:
     """Analytic rotor chain for (model × shape × mesh): per-device activation
-    sizes from eval_shape ÷ DP shard factor, times from analytic FLOPs."""
+    sizes from eval_shape ÷ DP shard factor, times from analytic FLOPs over
+    the peak of the mesh's device kind."""
     from ..core.planner import profile_stages_analytic
 
     cfg = model.cfg
@@ -113,7 +116,8 @@ def plan_chain(model: StagedLM, batch_specs: Dict, mesh, rules) -> Chain:
     stage_specs = model.stage_params(params_spec)
     chain = profile_stages_analytic(
         model.stage_fns(), stage_specs, batch_specs,
-        peak_flops=PEAK_FLOPS_BF16, activation_shard_factor=factor,
+        peak_flops=device_peaks(mesh.devices.flat[0]).flops_bf16,
+        activation_shard_factor=factor,
         flops_fwd=fwd, flops_bwd=bwd)
     # the head stage's residuals (logits) additionally shard on the model
     # axis when the vocab divides it — fold that into its per-device sizes
@@ -150,7 +154,8 @@ def plan_training(model: StagedLM, batch_specs: Dict, mesh, rules,
         policy, chain, num_slots=num_slots, impl=impl,
         # only 'auto' budgets need the parameter footprint — trace lazily
         auto_budget=lambda: activation_budget_bytes(
-            jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh.size))
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh.size,
+            hbm_bytes(mesh.devices.flat[0])))
     if jit_only and plan.uses_offload:
         print("[plan] offload plan needs the host tier; jitted two-tier "
               "fallback at the same device budget", flush=True)

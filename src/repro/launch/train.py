@@ -22,6 +22,8 @@ import jax
 from ..configs import get_config, smoke_config
 from ..distributed.fault_tolerance import elastic_plan
 from ..runtime.train_loop import TrainLoopConfig, run_training
+from .compile_cache import enable_compile_cache
+from .mesh import make_mesh
 
 
 def main(argv=None) -> int:
@@ -45,13 +47,14 @@ def main(argv=None) -> int:
                     choices=("banded", "pallas", "pallas_fused", "reference"),
                     help="DP fill kernels: banded numpy, the per-band Pallas"
                          " kernel, the fused single-dispatch Pallas fill"
-                         " (both jit on TPU, interpret on CPU), or the seed"
+                         " (both compiled for a TPU), or the seed"
                          " float64 path (default: banded / REPRO_DP_IMPL)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--override", default=None, help="JSON config overrides")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ov = json.loads(args.override) if args.override else {}
     cfg = smoke_config(args.arch, **ov) if args.smoke else get_config(args.arch, **ov)
@@ -59,7 +62,7 @@ def main(argv=None) -> int:
     n = len(jax.devices())
     (data, model_par), axes, accum = elastic_plan(n, args.model_parallel,
                                                   args.global_batch)
-    mesh = jax.make_mesh((data, model_par), axes)
+    mesh = make_mesh((data, model_par), axes)
     print(f"[train] arch={cfg.name} mesh={dict(mesh.shape)} "
           f"devices={n} accum={accum}")
 

@@ -14,7 +14,6 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map_unchecked
 from ..distributed.sharding import constrain
 from .common import dense_apply, dense_init
 
@@ -183,8 +182,8 @@ def moe_apply_ep(p: Params, cfg, x: jax.Array, mesh, dp_axes, ep_axis="model"
         return y.reshape(Bl, S_, d_), aux
 
     P_ = jax.sharding.PartitionSpec
-    fn = shard_map_unchecked(
-        local_fn, mesh=mesh,
+    fn = jax.shard_map(
+        local_fn, mesh=mesh, check_vma=False,
         in_specs=(P_(), P_(ep_axis), P_(ep_axis), P_(ep_axis),
                   P_(dp_axes if dp_axes else None)),
         out_specs=(P_(dp_axes if dp_axes else None), P_()))

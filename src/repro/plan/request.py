@@ -171,7 +171,8 @@ class PlanRequest:
       ``repro.core.dp_kernels.KNOWN_IMPLS``; ``None`` → the solver default /
       ``REPRO_DP_IMPL``).  ``"pallas"`` runs the band fill on the per-band
       Pallas kernel, ``"pallas_fused"`` on the single-dispatch
-      device-resident fill (both jit on TPU, interpret-mode CPU fallback).
+      device-resident fill (both compiled for a TPU; interpret mode only
+      via ``repro.kernels.dp_fill.ops.set_interpret``).
     - ``on_infeasible`` — ``"raise"`` (default: :class:`repro.plan
       .InfeasiblePlanError`) or ``"min_memory"`` (fall back to the
       smallest-memory feasible schedule and report its true need).

@@ -32,7 +32,7 @@ class ServeLoopConfig:
     eos_id: Optional[int] = None
 
 
-def _serve_fns(model, max_len: int):
+def serve_fns(model, max_len: int):
     """Jitted prefill/decode pair, memoized per (model instance, max_len) so
     repeated `run_serving` calls (benchmark sweeps) don't retrace."""
     memo = model.__dict__.setdefault("_serve_jit", {})
@@ -107,7 +107,7 @@ def run_serving(cfg, params, prompts: np.ndarray, loop: ServeLoopConfig,
                                 kv_policy=kv_policy, kv_budget=kv_budget,
                                 host=host, host_buffer=host_buffer)
 
-    prefill, decode = _serve_fns(model, loop.max_len)
+    prefill, decode = serve_fns(model, loop.max_len)
 
     ts0 = tracer.now() if rec else 0.0
     t0 = time.perf_counter()

@@ -101,7 +101,9 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
         tracer = Tracer(name="train")
 
     model = StagedLM(cfg)
-    mesh = mesh or jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    if mesh is None:
+        from ..launch.mesh import make_mesh
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
     rules = DEFAULT_RULES
     opt_cfg = AdamWConfig(lr=loop.lr)
     lr_fn = linear_warmup_cosine(loop.lr, loop.warmup, loop.steps)
@@ -135,8 +137,10 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
         elif plan is not None:
             tree = plan.tree
         if tree is not None:
+            budget = ("" if plan.budget_bytes is None else
+                      f", device budget {plan.budget_bytes / 2**30:.3f} GiB")
             log_fn(f"[rotor] plan: {count_checkpoint_scopes(tree)} checkpoint "
-                   f"scopes over {model.n_stages()} stages")
+                   f"scopes over {model.n_stages()} stages{budget}")
         from ..core import solver_cache
         st = solver_cache.stats()
         if st["hits"] or st["misses"]:
@@ -183,7 +187,7 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                                host_count=loop.data_host_count)
         data.start(from_step=start_step)
         watchdog = StragglerWatchdog(threshold=loop.straggler_threshold)
-        losses = []
+        losses, grad_norms, step_seconds = [], [], []
         t_begin = time.perf_counter()
         step = start_step
         try:
@@ -207,6 +211,8 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                     t1 = tracer.now()
                     tracer.record("Step", step, t1 - step_s, t1)
                 losses.append(loss)
+                grad_norms.append(float(metrics["grad_norm"]))
+                step_seconds.append(step_s)
                 ev = watchdog.step_end(step)
                 if ev is not None:
                     log_fn(f"[watchdog] straggler at step {ev.step}: "
@@ -221,7 +227,8 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                     break
                 if step % loop.log_every == 0:
                     log_fn(f"step {step:5d} loss {loss:.4f} "
-                           f"gnorm {float(metrics['grad_norm']):.3f}")
+                           f"gnorm {grad_norms[-1]:.3f} "
+                           f"time {step_s:.3f}s")
                 if (manager is not None and loop.ckpt_every
                         and step and step % loop.ckpt_every == 0):
                     manager.save(step, {"params": params, "opt": opt_state,
@@ -237,7 +244,9 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                                 "step": jnp.asarray(step, jnp.int32)},
                          blocking=True)
         tokens = loop.global_batch * loop.seq_len * max(len(losses), 1)
-        result = {"losses": losses, "params": params, "opt_state": opt_state,
+        result = {"losses": losses, "grad_norms": grad_norms,
+                  "step_seconds": step_seconds,
+                  "plan": plan, "params": params, "opt_state": opt_state,
                   "last_step": step, "wall_s": wall,
                   "tokens_per_s": tokens / max(wall, 1e-9),
                   "straggler_events": len(watchdog.events)}

@@ -13,3 +13,16 @@ os.environ.setdefault("REPRO_SOLVER_CACHE_DIR", "")
 import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", False)
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="session", autouse=True)
+def _pallas_dp_fill_interpreted():
+    """The Pallas DP fills compile only for a TPU: the tests that plan with
+    ``impl="pallas"`` / ``"pallas_fused"`` run them in interpret mode."""
+    from repro.kernels.dp_fill import ops
+
+    ops.set_interpret(True)
+    yield
+    ops.set_interpret(False)

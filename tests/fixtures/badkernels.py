@@ -85,7 +85,7 @@ def _racy_fused_kernel(
             return jnp.minimum(acc, cand)
 
         acc = jax.lax.fori_loop(0, d, split, jnp.full((BR, W), inf, COST_DT))
-        mn = pl.load(mn_ref, (pl.ds(d - 1, 1), pl.ds(r0, BR)))[0][:, None]
+        mn = mn_ref[pl.ds(d - 1, 1), pl.ds(r0, BR)][0][:, None]
         res = jnp.where(cols < mn, inf, acc)
         t_ref[pl.ds(off_ref[d] + r0, BR), :] = res
 
@@ -140,7 +140,7 @@ def _oob_fused_kernel(
             return jnp.minimum(acc, cand)
 
         acc = jax.lax.fori_loop(0, d, split, jnp.full((BR, W), inf, COST_DT))
-        mn = pl.load(mn_ref, (pl.ds(d - 1, 1), pl.ds(r0, BR)))[0][:, None]
+        mn = mn_ref[pl.ds(d - 1, 1), pl.ds(r0, BR)][0][:, None]
         res = jnp.where(cols < mn, inf, acc)
         # BUG: the write escapes the padded row margin (nrows = ncells +
         # 2L + BR); the driver's slack absorbs at most 2L + BR - 1 rows.

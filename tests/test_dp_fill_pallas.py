@@ -5,9 +5,9 @@ f32-exact chains ``tests/test_dp_kernels.py`` uses (integer stage costs,
 dyadic transfer times — every DP quantity exactly representable in float32,
 so equality is bit-exact, not approximate).
 
-Interpret mode executes the kernel bodies in Python on CPU — the same
-dispatch seam both impls fall back to automatically off-TPU — so this suite
-runs in CPU CI and kernel regressions no longer need a TPU to surface.  The
+Interpret mode (set for every test by ``conftest.py``) executes the kernel
+bodies in Python on CPU, so this suite runs in CPU CI and kernel
+regressions no longer need a TPU to surface.  The
 fused impl additionally carries a *single-dispatch* contract: one
 ``pallas_call`` per fill, no per-band host loop — asserted below via a
 counting shim on ``pallas_call``.
@@ -31,13 +31,6 @@ from repro.offload.solver import solve_optimal_offload
 from repro.plan import PlanRequest, build_plan
 
 from helpers import random_chain
-
-
-@pytest.fixture(autouse=True)
-def interpret_mode():
-    dpo.set_interpret(True)
-    yield
-    dpo.set_interpret(None)
 
 
 #: Both Pallas two-tier fills behind one parametrization knob.
@@ -397,7 +390,18 @@ def test_plan_request_rejects_unknown_impl():
 
 
 def test_interpret_dispatch_default_is_backend_based():
-    dpo.set_interpret(None)
-    assert dpo.interpret_mode() == (jax.default_backend() != "tpu")
-    dpo.set_interpret(True)
+    """The default is compiled dispatch, which raises off a TPU and names
+    the backend: interpret mode is asked for, never chosen by backend."""
+    assert jax.default_backend() != "tpu"
+    dpo.set_interpret(False)
+    try:
+        with pytest.raises(RuntimeError,
+                           match=f"backend is '{jax.default_backend()}'"):
+            dpo.interpret_mode()
+        ch = random_chain(np.random.default_rng(1), max_len=3)
+        with pytest.raises(RuntimeError, match="set_interpret"):
+            solve_optimal(ch, _budgets(ch, (0.8,))[0], num_slots=20,
+                          impl="pallas_fused", cache=False)
+    finally:
+        dpo.set_interpret(True)
     assert dpo.interpret_mode() is True

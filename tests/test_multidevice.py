@@ -31,8 +31,8 @@ def test_sharded_train_step_pod_mesh():
         from repro.distributed.sharding import axis_rules
         from repro.configs.shapes import ShapeSpec, input_specs
         from repro.launch.steps import build_cell
-        from repro.launch.mesh import make_production_mesh
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = smoke_config("qwen1.5-4b", n_heads=4, n_kv_heads=4, vocab_size=256)
         shape = ShapeSpec("t", "train", 32, 8)
         with axis_rules(mesh):
@@ -59,6 +59,7 @@ def test_sharded_train_step_pod_mesh():
 def test_moe_shard_map_matches_local():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import smoke_config
         from repro.distributed.sharding import axis_rules
@@ -68,7 +69,7 @@ def test_moe_shard_map_matches_local():
         p = mlp_mod.moe_init(key, cfg, jnp.float32)
         x = jax.random.normal(jax.random.fold_in(key, 1), (4, 8, cfg.d_model))
         y_local, aux_local = mlp_mod.moe_apply(p, cfg, x)  # no mesh: local path
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         with axis_rules(mesh):
             y_ep, aux_ep = jax.jit(lambda p, x: mlp_mod.moe_apply(p, cfg, x))(p, x)
         np.testing.assert_allclose(np.asarray(y_local, np.float64),
@@ -82,9 +83,10 @@ def test_moe_shard_map_matches_local():
 def test_elastic_restore_8_to_4():
     code_save = """
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.ckpt.manager import CheckpointManager
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         w = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                            NamedSharding(mesh, P("data", None)))
         CheckpointManager("/tmp/elastic_ck", keep=1).save(3, {"w": w})
@@ -92,9 +94,10 @@ def test_elastic_restore_8_to_4():
     """
     code_load = """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.ckpt.manager import CheckpointManager
         from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         target = {"w": jax.ShapeDtypeStruct((8, 8), jnp.float32)}
         shards = {"w": NamedSharding(mesh, P("data", None))}
         step, st = CheckpointManager("/tmp/elastic_ck").restore(
@@ -112,9 +115,10 @@ def test_elastic_restore_8_to_4():
 def test_compressed_allreduce_on_mesh():
     out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.distributed.compression import compressed_psum_mean, ef_init
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         # per-member gradients: leading axis = member
         g_all = jnp.arange(4 * 8, dtype=jnp.float32).reshape(4, 8) / 7.0
 
@@ -124,10 +128,9 @@ def test_compressed_allreduce_on_mesh():
             mean, e2 = compressed_psum_mean(g, e, axes=("data",), n_members=4)
             return mean["w"][None], e2["w"][None]
 
-        from repro.compat import shard_map_unchecked
-        fn = jax.jit(shard_map_unchecked(per_member, mesh=mesh,
-                                         in_specs=(P("data"), P("data")),
-                                         out_specs=(P("data"), P("data"))))
+        fn = jax.jit(jax.shard_map(per_member, mesh=mesh, check_vma=False,
+                                   in_specs=(P("data"), P("data")),
+                                   out_specs=(P("data"), P("data"))))
         mean, e2 = fn(g_all, jnp.zeros((4, 8)))
         true_mean = np.asarray(g_all).mean(axis=0)
         got = np.asarray(mean)[0]
@@ -150,7 +153,8 @@ def test_dryrun_entrypoint_smoke():
     code = """
 import repro.launch.dryrun as dr
 import jax
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 import repro.launch.mesh as m
 m.make_production_mesh = lambda multi_pod=False: mesh
 rec = dr.run_cell("qwen1.5-4b", "train_4k", False, "rotor:auto",

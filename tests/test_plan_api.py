@@ -369,6 +369,7 @@ def test_num_slots_threads_through_launch_planner():
     from repro.configs import smoke_config
     from repro.configs.shapes import ShapeSpec, input_specs
     from repro.distributed.sharding import DEFAULT_RULES, axis_rules
+    from repro.launch.mesh import make_mesh
     from repro.launch.steps import plan_training
     from repro.models.lm import StagedLM
     from repro.runtime.train_loop import TrainLoopConfig
@@ -376,7 +377,7 @@ def test_num_slots_threads_through_launch_planner():
     cfg = smoke_config("qwen1.5-4b", num_layers=4, layer_kinds=("dense",) * 4,
                        n_chunks=4)
     model = StagedLM(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeSpec("train", "train", 16, 2)
     with axis_rules(mesh, DEFAULT_RULES):
         batch_specs = input_specs(cfg, shape)

@@ -151,12 +151,8 @@ else:
         m = float(math.ceil(sa.peak_mem * frac))
         S = int(m)
         dchain = ch.discretize(m, S)
-        dpo.set_interpret(True)
-        try:
-            band = dp_kernels.fill_two_tier(dchain, S, allow_fall=allow_fall)
-            fused = dpo.fill_two_tier_fused(dchain, S, allow_fall=allow_fall)
-        finally:
-            dpo.set_interpret(None)
+        band = dp_kernels.fill_two_tier(dchain, S, allow_fall=allow_fall)
+        fused = dpo.fill_two_tier_fused(dchain, S, allow_fall=allow_fall)
         assert np.array_equal(band.data, fused.data, equal_nan=True)
 
 
@@ -171,12 +167,8 @@ else:
         sa = simulate(hch, Schedule.store_all(hch.length))
         S = int(math.ceil(sa.peak_mem * 0.7))
         dchain = hch.discretize(float(S), S)
-        dpo.set_interpret(True)
-        try:
-            tb, te = dp_kernels.fill_offload(dchain, S, allow_fall=allow_fall)
-            fb, fe = dpo.fill_offload_fused(dchain, S, allow_fall=allow_fall)
-        finally:
-            dpo.set_interpret(None)
+        tb, te = dp_kernels.fill_offload(dchain, S, allow_fall=allow_fall)
+        fb, fe = dpo.fill_offload_fused(dchain, S, allow_fall=allow_fall)
         assert np.array_equal(tb.data, fb.data, equal_nan=True)
         assert np.array_equal(te.data, fe.data, equal_nan=True)
 
