@@ -52,7 +52,7 @@ def main():
     out = run_training(cfg, loop)
     print(f"[example] loss {out['losses'][0]:.3f} -> {out['losses'][-1]:.3f} "
           f"over {len(out['losses'])} steps; "
-          f"{out['tokens_per_s']:.0f} tokens/s")
+          f"{out['tokens_per_s']:.0f} tokens/s after the first step")
 
 
 if __name__ == "__main__":

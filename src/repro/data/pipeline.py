@@ -4,7 +4,8 @@ Production posture: per-host sharded generation (each host materializes only
 its slice of the global batch), deterministic per (seed, step) so that a
 checkpoint-restart resumes the *exact* stream — a fault-tolerance requirement
 (the restarted run must consume the same data as the lost one).  A background
-thread prefetches ``prefetch`` batches ahead.
+thread prefetches ``prefetch`` batches ahead; each batch it makes is one
+``data.batch`` span (:func:`repro.obs.trace.span`) in a profiler trace.
 """
 
 from __future__ import annotations
@@ -16,6 +17,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..obs.trace import span
 
 
 def make_batch_specs(cfg, batch: int, seq: int) -> Dict[str, jax.ShapeDtypeStruct]:
@@ -88,13 +91,16 @@ class SyntheticLMData:
         self._stop = threading.Event()
 
         def worker():
-            s = from_step
+            s, b = from_step, None
             while not self._stop.is_set():
+                if b is None:  # a batch the full queue refused is kept
+                    with span("data.batch", step=s):
+                        b = self.batch_at(s)
                 try:
-                    self._q.put(self.batch_at(s), timeout=0.5)
-                    s += 1
+                    self._q.put(b, timeout=0.5)
                 except queue.Full:
                     continue
+                s, b = s + 1, None
 
         self._thread = threading.Thread(target=worker, daemon=True)
         self._thread.start()

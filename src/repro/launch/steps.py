@@ -20,6 +20,8 @@ from ..distributed.sharding import (DEFAULT_RULES, LONG_CONTEXT_RULES,
                                     spec_for)
 from ..models.flops import stage_flops
 from ..models.lm import StagedLM
+from ..obs import metrics as obs_metrics
+from ..obs.trace import span
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
 
 
@@ -81,22 +83,28 @@ def opt_axes(param_axes: Any) -> Dict[str, Any]:
 # rotor planning at scale
 # ---------------------------------------------------------------------------
 
-def activation_budget_bytes(params_spec: Any, n_devices: int, hbm: int,
-                            slack: float = 0.9) -> float:
-    """Per-device activation budget = HBM − (params + grads + Adam moments),
-    assuming full (FSDP×TP) sharding of all three (ZeRO-3 via GSPMD).
-    ``hbm`` is the planned device's memory (:func:`repro.core.devices
-    .hbm_bytes`)."""
+def state_bytes(params_spec: Any, n_devices: int) -> float:
+    """Per-device bytes of params + grads + Adam moments, assuming full
+    (FSDP×TP) sharding of all three (ZeRO-3 via GSPMD)."""
     p_bytes = sum(int(math.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
                   for l in jax.tree.leaves(params_spec))
-    per_dev_states = p_bytes * (1 + 1 + 4) / n_devices  # bf16 p+g, f32 m+v
-    return max(hbm * slack - per_dev_states, hbm * 0.05)
+    return p_bytes * (1 + 1 + 4) / n_devices  # bf16 p+g, f32 m+v
 
 
-def plan_chain(model: StagedLM, batch_specs: Dict, mesh, rules) -> Chain:
+def activation_budget_bytes(params_spec: Any, n_devices: int, hbm: int,
+                            slack: float = 0.9) -> float:
+    """Per-device activation budget = HBM − :func:`state_bytes`.
+    ``hbm`` is the planned device's memory (:func:`repro.core.devices
+    .hbm_bytes`)."""
+    return max(hbm * slack - state_bytes(params_spec, n_devices), hbm * 0.05)
+
+
+def plan_chain(model: StagedLM, batch_specs: Dict, mesh, rules,
+               params_spec: Any = None) -> Chain:
     """Analytic rotor chain for (model × shape × mesh): per-device activation
     sizes from eval_shape ÷ DP shard factor, times from analytic FLOPs over
-    the peak of the mesh's device kind."""
+    the peak of the mesh's device kind.  ``params_spec`` is
+    ``jax.eval_shape(model.init, ...)``, traced here when not given."""
     from ..core.planner import profile_stages_analytic
 
     cfg = model.cfg
@@ -112,7 +120,8 @@ def plan_chain(model: StagedLM, batch_specs: Dict, mesh, rules) -> Chain:
             dp *= mesh.shape[ax]
     factor = dp if B % dp == 0 else 1
     fwd, bwd = stage_flops(cfg, B, S)
-    params_spec = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    if params_spec is None:
+        params_spec = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     stage_specs = model.stage_params(params_spec)
     chain = profile_stages_analytic(
         model.stage_fns(), stage_specs, batch_specs,
@@ -144,22 +153,34 @@ def plan_training(model: StagedLM, batch_specs: Dict, mesh, rules,
     from a remat tree, so an offload-bearing plan is degraded to the best
     two-tier plan at the same device budget (the eager runtime path — see
     ``runtime/train_loop.py`` — runs the true offload schedule instead).
+
+    Profiling the chain and solving are the ``plan.chain`` and
+    ``plan.solve`` spans; their seconds, the plan's predicted step time and
+    its whole per-device picture of the step (activation peak plus
+    :func:`state_bytes`) are the gauges ``plan.chain_s``, ``plan.solve_s``,
+    ``plan.predicted_step_s`` and ``plan.planned_bytes``.
     """
     cfg = model.cfg
     policy = policy if policy is not None else cfg.remat_policy
     if policy == "none":
         return None, None
-    chain = plan_chain(model, batch_specs, mesh, rules)
-    plan = resolve_policy(
-        policy, chain, num_slots=num_slots, impl=impl,
-        # only 'auto' budgets need the parameter footprint — trace lazily
-        auto_budget=lambda: activation_budget_bytes(
-            jax.eval_shape(model.init, jax.random.PRNGKey(0)), mesh.size,
-            hbm_bytes(mesh.devices.flat[0])))
+    with span("plan.chain") as chain_span:
+        params_spec = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        chain = plan_chain(model, batch_specs, mesh, rules, params_spec)
+    with span("plan.solve") as solve_span:
+        plan = resolve_policy(
+            policy, chain, num_slots=num_slots, impl=impl,
+            auto_budget=lambda: activation_budget_bytes(
+                params_spec, mesh.size, hbm_bytes(mesh.devices.flat[0])))
     if jit_only and plan.uses_offload:
         print("[plan] offload plan needs the host tier; jitted two-tier "
               "fallback at the same device budget", flush=True)
         plan = two_tier_fallback(plan, chain)
+    obs_metrics.gauge("plan.chain_s").set(chain_span.seconds)
+    obs_metrics.gauge("plan.solve_s").set(solve_span.seconds)
+    obs_metrics.gauge("plan.predicted_step_s").set(plan.expected_time)
+    obs_metrics.gauge("plan.planned_bytes").set(
+        plan.peak_device_mem + state_bytes(params_spec, mesh.size))
     return plan, chain
 
 

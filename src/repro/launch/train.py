@@ -75,7 +75,7 @@ def main(argv=None) -> int:
     out = run_training(cfg, loop, mesh=mesh)
     print(f"[train] done: {len(out['losses'])} steps, "
           f"loss {out['losses'][0]:.4f} -> {out['losses'][-1]:.4f}, "
-          f"{out['tokens_per_s']:.0f} tok/s")
+          f"{out['tokens_per_s']:.0f} tok/s after the first step")
     return 0
 
 

@@ -35,6 +35,16 @@ from .common import (dense_apply, dense_init, rms_norm, rms_norm_init,
 Params = Dict[str, Any]
 
 
+def _scoped(name: str, fn):
+    """``fn`` with the operations it traces under ``jax.named_scope(name)``."""
+
+    def stage(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    return stage
+
+
 @dataclasses.dataclass(frozen=True)
 class CacheLayout:
     """Byte layout of a decode cache (see :meth:`StagedLM.cache_layout`).
@@ -469,10 +479,14 @@ class StagedLM:
         return loss + a["aux"]
 
     def stage_fns(self) -> List[Any]:
-        fns: List[Any] = [lambda p, batch: self._embed_stage(p, batch)]
+        """The chain's stage functions, each under a ``jax.named_scope``
+        (``stage.embed``, ``stage.chunk<i>``, ``stage.head``) that names its
+        operations, recomputed ones included, on the device."""
+        fns: List[Any] = [_scoped("stage.embed", self._embed_stage)]
         for i in range(len(self.cfg.chunks)):
-            fns.append(functools.partial(self._chunk_stage, i))
-        fns.append(lambda p, a: self._head_stage(p, a))
+            fns.append(_scoped(f"stage.chunk{i}",
+                               functools.partial(self._chunk_stage, i)))
+        fns.append(_scoped("stage.head", self._head_stage))
         return fns
 
     # -- plain & rotor forward ---------------------------------------------

@@ -2,8 +2,10 @@
 
 Three layers, one loop:
 
-- :mod:`repro.obs.trace` — a lightweight span recorder both executors emit
-  per-op spans into; exports Chrome/Perfetto ``trace.json`` and the
+- :mod:`repro.obs.trace` — :func:`~repro.obs.trace.span`, the one span API
+  (a named interval on the profiler's clock, recorded also in a tracer when
+  given), and a lightweight span recorder both executors emit per-op spans
+  into; exports Chrome/Perfetto ``trace.json`` and the
   :meth:`~repro.plan.MemoryPlan.timeline` schema so predicted and measured
   timelines render side by side.
 - :mod:`repro.obs.metrics` — a process-wide counters/gauges/histograms
@@ -18,8 +20,8 @@ Three layers, one loop:
   convergence).
 
 Everything here is stdlib + numpy only at import time (jax is touched
-lazily, only to fence traced ops), so the numpy core can report without
-dragging in an accelerator runtime.
+lazily, to open profiler annotations and to fence traced ops), so the
+numpy core can report without dragging in an accelerator runtime.
 """
 
 from . import metrics
@@ -28,6 +30,7 @@ from .trace import (
     Span,
     Tracer,
     measured_stage_times,
+    span,
     validate_perfetto,
     validate_trace_file,
 )
@@ -37,6 +40,7 @@ __all__ = [
     "Span",
     "Tracer",
     "measured_stage_times",
+    "span",
     "validate_perfetto",
     "validate_trace_file",
     "DriftReport",

@@ -1,4 +1,11 @@
-"""Lightweight span recorder for planned execution.
+"""Lightweight span recorder for planned execution, on the profiler's clock.
+
+:func:`span` is the one way to time a block of host code: it opens a
+``jax.profiler.TraceAnnotation`` under the span's name, so the block shows
+in any profiler trace taken around it (``jax.profiler.trace``, Perfetto,
+TensorBoard) on the same clock as the device's operations, and, given an
+enabled :class:`Tracer`, also records the interval as a :class:`Span`.
+With no profiler session and no tracer it costs about a microsecond.
 
 Both executors — the op-faithful eager walker
 (:func:`repro.offload.executor.execute_offload_schedule`, reached through
@@ -6,8 +13,9 @@ Both executors — the op-faithful eager walker
 nested-remat binding (:class:`repro.plan.plan.BoundPlan`, behind an opt-in
 flag) — emit one :class:`Span` per schedule op into a :class:`Tracer`:
 op kind (``Fall``/``Fck``/``Fnone``/``B``/``Foff``/``Prefetch``, plus
-``Decode`` from the serving loop and ``Step`` from the train loop), op
-index, bytes moved/produced where cheap to know, and wall time.
+``Decode``/``Step`` from the serving loop and the ``train.*`` spans of the
+train loop), op index, bytes moved/produced where cheap to know, and wall
+time.
 
 The recorder is deliberately dumb: ``record`` appends a dataclass to a
 list.  All interpretation lives in the exporters —
@@ -52,6 +60,7 @@ _OP_CATEGORY = {
     "Foff": CAT_TRANSFER,
     "Prefetch": CAT_TRANSFER,
     "Step": CAT_STEP,
+    "train.step": CAT_STEP,
     "Decode": CAT_DECODE,
 }
 
@@ -108,9 +117,10 @@ class Tracer:
             return
         self.spans.append(Span(op, arg, t_start, t_end, **kw))
 
-    def span(self, op: str, arg: Any = None, **kw) -> "_SpanCtx":
-        """Context manager measuring the block as one span."""
-        return _SpanCtx(self, op, arg, kw)
+    def span(self, op: str, arg: Any = None, **kw) -> "_Interval":
+        """Context manager measuring the block as one span (see
+        :func:`span`; ``kw`` are :class:`Span` fields)."""
+        return span(op, self, arg=arg, **kw)
 
     def fence(self, value: Any) -> None:
         """Block on a jax value (when ``sync``), so the enclosing span's end
@@ -229,21 +239,67 @@ class Tracer:
         return tr
 
 
-class _SpanCtx:
-    __slots__ = ("_tr", "_op", "_arg", "_kw", "_t0")
+class _Interval:
+    """An open :func:`span`: the profiler annotation, the start time, and
+    what the tracer's :class:`Span` gets."""
 
-    def __init__(self, tracer: Tracer, op: str, arg: Any, kw: Dict[str, Any]):
-        self._tr = tracer
-        self._op = op
+    __slots__ = ("_name", "_tracer", "_arg", "_fields", "_ann", "_t0",
+                 "seconds")
+
+    def __init__(self, name: str, tracer: Optional[Tracer], arg: Any,
+                 fields: Dict[str, Any], ann: Any):
+        self._name = name
+        self._tracer = tracer
         self._arg = arg
-        self._kw = kw
+        self._fields = fields
+        self._ann = ann
+        self.seconds = None
 
-    def __enter__(self) -> "_SpanCtx":
-        self._t0 = self._tr.now()
+    def note(self, **fields) -> None:
+        """Set :class:`Span` fields known only at the block's end."""
+        self._fields.update(fields)
+
+    def __enter__(self) -> "_Interval":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tr.record(self._op, self._arg, self._t0, self._tr.now(), **self._kw)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self.seconds = t1 - self._t0
+        tr = self._tracer
+        if tr is not None and tr.enabled:
+            tr.record(self._name, self._arg, self._t0 - tr._epoch,
+                      t1 - tr._epoch, **self._fields)
+
+
+def span(name: str, tracer: Optional[Tracer] = None, *,
+         step: Optional[int] = None, arg: Any = None,
+         marks_step: bool = False, **fields) -> _Interval:
+    """Time the ``with`` block as one named interval on the profiler's clock.
+
+    Opens ``jax.profiler.TraceAnnotation(name, step=step, arg=arg)`` (the
+    arguments that are given; the profiler keeps them beside the name, so
+    the event is named ``name`` exactly).  ``marks_step=True`` opens a
+    ``StepTraceAnnotation(name, step_num=step)`` instead: the profiler's
+    step marker.  When ``tracer`` is given and enabled, the interval is
+    also recorded there as a :class:`Span` (``op=name``, ``arg`` or else
+    ``step``, and ``fields``).  The object the block gets has ``note()``
+    for fields known only at its end, and ``seconds`` once it closes.
+    """
+    from jax import profiler  # the module stays stdlib at import
+
+    if marks_step:
+        ann = profiler.StepTraceAnnotation(name, step_num=step)
+    else:
+        kw = {}
+        if step is not None:
+            kw["step"] = step
+        if arg is not None:
+            kw["arg"] = arg
+        ann = profiler.TraceAnnotation(name, **kw)
+    return _Interval(name, tracer, step if arg is None else arg, fields, ann)
 
 
 # ---------------------------------------------------------------------------

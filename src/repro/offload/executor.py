@@ -18,6 +18,7 @@ to bound host memory or read back byte-exact peak accounting.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -119,82 +120,80 @@ def execute_offload_schedule(
 
     rec = tracer is not None and tracer.enabled
     for kind, l in schedule.ops:
-        if rec:
-            t0 = tracer.now()
-            produced = None     # value fenced before the span closes
+        with (tracer.span(kind, int(l)) if rec else nullcontext()) as sp:
+            produced = None     # value fenced before a traced span closes
             moved: Optional[int] = None
-        if kind == F_OFF:
-            i = int(l)
-            if i not in acts:
-                raise RuntimeError(
-                    f"Foff: a^{i} not live as a bare activation")
-            host_copy = _to_host(acts[i], host_device)
-            nbytes = _tree_bytes(host_copy)
-            host_buffer.put(i, host_copy, nbytes=nbytes)
-            if rec:
-                produced, moved = host_copy, nbytes
-        elif kind == PREFETCH:
-            i = int(l)
-            if i in acts:
-                raise RuntimeError(f"Prefetch: a^{i} already on device")
-            acts[i] = _to_device(host_buffer.pop(i), device, donate=True)
-            if rec:
-                produced = acts[i]
-                moved = _tree_bytes(produced)
-        elif kind in (F_NONE, F_CK, F_ALL):
-            a_in = get_act(l - 1)
-            if kind == F_ALL:
-                out, vjp_fn = jax.vjp(stages[l - 1], params[l - 1], a_in)
-                vjps[l] = vjp_fn
-                outs[l] = out
-                if l == L + 1:
-                    final_out = out
-            else:
-                out = stages[l - 1](params[l - 1], a_in)
-                acts[l] = out
-                if l == L + 1:
-                    final_out = out
-            if kind == F_NONE:
-                acts.pop(l - 1, None)
-            if rec:
-                produced = out
-                moved = _tree_bytes(out)
-        elif kind == BWD:
-            if l == L + 1:
-                out = outs[l]
-                if loss_cotangent is not None:
-                    delta = loss_cotangent
+            if kind == F_OFF:
+                i = int(l)
+                if i not in acts:
+                    raise RuntimeError(
+                        f"Foff: a^{i} not live as a bare activation")
+                host_copy = _to_host(acts[i], host_device)
+                nbytes = _tree_bytes(host_copy)
+                host_buffer.put(i, host_copy, nbytes=nbytes)
+                if rec:
+                    produced, moved = host_copy, nbytes
+            elif kind == PREFETCH:
+                i = int(l)
+                if i in acts:
+                    raise RuntimeError(f"Prefetch: a^{i} already on device")
+                acts[i] = _to_device(host_buffer.pop(i), device, donate=True)
+                if rec:
+                    produced = acts[i]
+                    moved = _tree_bytes(produced)
+            elif kind in (F_NONE, F_CK, F_ALL):
+                a_in = get_act(l - 1)
+                if kind == F_ALL:
+                    out, vjp_fn = jax.vjp(stages[l - 1], params[l - 1], a_in)
+                    vjps[l] = vjp_fn
+                    outs[l] = out
+                    if l == L + 1:
+                        final_out = out
                 else:
-                    delta = jax.tree.map(lambda o: jnp.ones_like(o), out)
+                    out = stages[l - 1](params[l - 1], a_in)
+                    acts[l] = out
+                    if l == L + 1:
+                        final_out = out
+                if kind == F_NONE:
+                    acts.pop(l - 1, None)
+                if rec:
+                    produced = out
+                    moved = _tree_bytes(out)
+            elif kind == BWD:
+                if l == L + 1:
+                    out = outs[l]
+                    if loss_cotangent is not None:
+                        delta = loss_cotangent
+                    else:
+                        delta = jax.tree.map(lambda o: jnp.ones_like(o), out)
+                else:
+                    delta = deltas.pop(l)
+                dparams, da = vjps.pop(l)(delta)
+                outs.pop(l, None)
+                grads[l - 1] = (dparams if grads[l - 1] is None else
+                                jax.tree.map(jnp.add, grads[l - 1], dparams))
+                deltas[l - 1] = da
+                acts.pop(l - 1, None)  # B^l consumes a^{l-1}
+                if rec:
+                    produced = (dparams, da)
             else:
-                delta = deltas.pop(l)
-            dparams, da = vjps.pop(l)(delta)
-            outs.pop(l, None)
-            grads[l - 1] = dparams if grads[l - 1] is None else jax.tree.map(
-                jnp.add, grads[l - 1], dparams)
-            deltas[l - 1] = da
-            acts.pop(l - 1, None)  # B^l consumes a^{l-1}
+                raise ValueError(f"offload executor cannot run op kind {kind}")
+            live = None
+            if track_live_bytes:
+                live = (_tree_bytes(acts) + _tree_bytes(vjps)
+                        + _tree_bytes(outs) + _tree_bytes(deltas))
+                peak_live = max(peak_live, live)
             if rec:
-                produced = (dparams, da)
-        else:
-            raise ValueError(f"offload executor cannot run op kind {kind}")
-        live = None
-        if track_live_bytes:
-            live = (_tree_bytes(acts) + _tree_bytes(vjps) + _tree_bytes(outs)
-                    + _tree_bytes(deltas))
-            peak_live = max(peak_live, live)
-        if rec:
-            tracer.fence(produced)
-            t1 = tracer.now()
-            tracer.record(kind, int(l), t0, t1, bytes=moved,
-                          host_mem=(float(host_buffer.bytes_in_use)
-                                    if kind in (F_OFF, PREFETCH) else None),
-                          device_mem=(float(live) if live is not None
-                                      else None))
-            if kind == PREFETCH:
-                # the prefetch is synchronous: its whole wall time is stall
-                metrics.histogram(
-                    "offload.prefetch_stall_seconds").observe(t1 - t0)
+                tracer.fence(produced)
+                sp.note(bytes=moved,
+                        host_mem=(float(host_buffer.bytes_in_use)
+                                  if kind in (F_OFF, PREFETCH) else None),
+                        device_mem=(float(live) if live is not None
+                                    else None))
+        if rec and kind == PREFETCH:
+            # the prefetch is synchronous: its whole wall time is stall
+            metrics.histogram(
+                "offload.prefetch_stall_seconds").observe(sp.seconds)
 
     if 0 not in deltas:
         raise RuntimeError("schedule did not produce δ^0")

@@ -39,9 +39,11 @@ def global_norm(tree: Any) -> jax.Array:
                         for x in jax.tree.leaves(tree)))
 
 
+@jax.named_scope("optimizer.adamw")
 def adamw_update(cfg: AdamWConfig, grads: Any, state: dict, params: Any,
                  lr: Optional[jax.Array] = None) -> Tuple[Any, dict, dict]:
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics); traced under the
+    ``optimizer.adamw`` scope, which names its operations on the device."""
     gnorm = global_norm(grads)
     if cfg.clip_norm is not None:
         scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-12))

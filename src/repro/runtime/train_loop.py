@@ -25,8 +25,22 @@ from ..distributed.sharding import DEFAULT_RULES, axis_rules
 from ..launch.steps import (batch_axes, make_train_step, opt_axes,
                             plan_training, shard_tree, sharding_of)
 from ..models.lm import StagedLM
+from ..obs import metrics as obs_metrics
+from ..obs.trace import span
 from ..optim.adamw import AdamWConfig, adamw_init, adamw_update
 from ..optim.schedules import linear_warmup_cosine
+
+
+def _record_step_bytes(compiled) -> None:
+    """Set ``train.step_bytes``: the compiled step's per-device bytes,
+    arguments + outputs − aliased (donated) + temporaries, from
+    ``memory_analysis()`` (left unset where the backend gives none)."""
+    ma = compiled.memory_analysis()
+    if ma is None:
+        return
+    obs_metrics.gauge("train.step_bytes").set(
+        ma.argument_size_in_bytes + ma.output_size_in_bytes
+        - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
 
 
 def _make_offload_step(model, opt_cfg: AdamWConfig, schedule, lr_fn,
@@ -87,14 +101,24 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                  tracer=None) -> Dict[str, Any]:
     """Train a StagedLM; returns final metrics + state handles.
 
-    ``tracer`` (a :class:`repro.obs.trace.Tracer`, opt-in) records per-op
-    spans on the eager offload path and one fenced ``Step`` span per step on
-    the jitted path; when the offload executor ran traced, the result dict
-    gains a ``drift`` report comparing the plan's predicted makespan against
-    the last (warmest) traced step.
+    Each iteration is a ``train.step`` span (the profiler's step marker)
+    holding ``train.data_wait`` (the prefetch queue), ``train.device_put``
+    (the batch transfer), ``train.dispatch`` (the step's call) and
+    ``train.sync`` (``float(loss)``, the host waiting for the device), each
+    with ``step=k`` (:func:`repro.obs.trace.span`).  The jitted step is
+    lowered and compiled once, on the first batch, inside ``train.compile``
+    (the jit's own calls then find that executable); the compiled step's
+    per-device bytes (arguments + outputs − aliased + temporaries) are the
+    gauge ``train.step_bytes``.
+
+    ``tracer`` (a :class:`repro.obs.trace.Tracer`, opt-in) records those
+    spans too, and per-op spans on the eager offload path; when the offload
+    executor ran traced, the result dict gains a ``drift`` report comparing
+    the plan's predicted makespan against the last (warmest) traced step.
+    ``tokens_per_s`` counts the steps after the first, from the end of the
+    first step (which compiles) to the end of the last.
     """
     from ..configs.shapes import ShapeSpec, input_specs
-    from ..obs import metrics as obs_metrics
 
     if tracer is None and loop.trace_path:
         from ..obs.trace import Tracer
@@ -155,6 +179,8 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
             step_fn = jax.jit(make_train_step(model, opt_cfg, tree, lr_fn,
                                               grad_accum=loop.grad_accum),
                               donate_argnums=(0, 1))
+        # the jitted step is compiled ahead of the first step, on its batch
+        compile_first = offload_plan is None
 
         params_spec = jax.eval_shape(model.init, jax.random.PRNGKey(loop.seed))
         p_shard = sharding_of(shard_tree(params_spec, model.param_axes(),
@@ -188,67 +214,80 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
         data.start(from_step=start_step)
         watchdog = StragglerWatchdog(threshold=loop.straggler_threshold)
         losses, grad_norms, step_seconds = [], [], []
-        t_begin = time.perf_counter()
+        t_first = t_last = None  # ends of the first and the last step
         step = start_step
         try:
             for step in range(start_step, loop.steps):
-                watchdog.step_begin()
-                host_batch = data.next()
-                batch = jax.tree.map(
-                    lambda arr, shd: jax.device_put(arr, shd),
-                    host_batch, b_shard)
-                t_step = time.perf_counter()
-                params, opt_state, metrics = step_fn(
-                    params, opt_state, batch, jnp.asarray(step, jnp.int32))
-                loss = float(metrics["loss"])  # blocks on the step's result
-                step_s = time.perf_counter() - t_step
-                obs_metrics.histogram("train.step_seconds").observe(step_s)
-                obs_metrics.gauge("train.loss").set(loss)
-                if (tracer is not None and tracer.enabled
-                        and offload_plan is None):
-                    # the offload executor already traced per-op spans; the
-                    # jitted path gets one fenced span per whole step
-                    t1 = tracer.now()
-                    tracer.record("Step", step, t1 - step_s, t1)
-                losses.append(loss)
-                grad_norms.append(float(metrics["grad_norm"]))
-                step_seconds.append(step_s)
-                ev = watchdog.step_end(step)
-                if ev is not None:
-                    log_fn(f"[watchdog] straggler at step {ev.step}: "
-                           f"{ev.duration:.2f}s vs median {ev.median:.2f}s")
-                if watchdog.should_restart:
-                    log_fn("[watchdog] persistent straggler — checkpointing "
-                           "for restart")
-                    if manager is not None:
-                        manager.save(step, {"params": params, "opt": opt_state,
-                                            "step": jnp.asarray(step, jnp.int32)},
-                                     blocking=True)
-                    break
-                if step % loop.log_every == 0:
-                    log_fn(f"step {step:5d} loss {loss:.4f} "
-                           f"gnorm {grad_norms[-1]:.3f} "
-                           f"time {step_s:.3f}s")
-                if (manager is not None and loop.ckpt_every
-                        and step and step % loop.ckpt_every == 0):
-                    manager.save(step, {"params": params, "opt": opt_state,
-                                        "step": jnp.asarray(step, jnp.int32)},
-                                 blocking=not loop.async_ckpt)
+                with span("train.step", tracer, step=step, marks_step=True):
+                    watchdog.step_begin()
+                    with span("train.data_wait", tracer, step=step):
+                        host_batch = data.next()
+                    with span("train.device_put", tracer, step=step):
+                        batch = jax.tree.map(
+                            lambda arr, shd: jax.device_put(arr, shd),
+                            host_batch, b_shard)
+                    step_arr = jnp.asarray(step, jnp.int32)
+                    if compile_first:
+                        with span("train.compile", tracer, step=step):
+                            compiled = step_fn.lower(params, opt_state, batch,
+                                                     step_arr).compile()
+                        _record_step_bytes(compiled)
+                        compile_first = False
+                    t_step = time.perf_counter()
+                    with span("train.dispatch", tracer, step=step):
+                        params, opt_state, metrics = step_fn(
+                            params, opt_state, batch, step_arr)
+                    with span("train.sync", tracer, step=step):
+                        loss = float(metrics["loss"])  # waits for the step
+                    t_last = time.perf_counter()
+                    step_s = t_last - t_step
+                    if t_first is None:
+                        t_first = t_last
+                    obs_metrics.histogram("train.step_seconds").observe(step_s)
+                    obs_metrics.gauge("train.loss").set(loss)
+                    losses.append(loss)
+                    grad_norms.append(float(metrics["grad_norm"]))
+                    step_seconds.append(step_s)
+                    ev = watchdog.step_end(step)
+                    if ev is not None:
+                        log_fn(f"[watchdog] straggler at step {ev.step}: "
+                               f"{ev.duration:.2f}s vs median {ev.median:.2f}s")
+                    if watchdog.should_restart:
+                        log_fn("[watchdog] persistent straggler — "
+                               "checkpointing for restart")
+                        if manager is not None:
+                            manager.save(step, {"params": params,
+                                                "opt": opt_state,
+                                                "step": step_arr},
+                                         blocking=True)
+                        break
+                    if step % loop.log_every == 0:
+                        log_fn(f"step {step:5d} loss {loss:.4f} "
+                               f"gnorm {grad_norms[-1]:.3f} "
+                               f"time {step_s:.3f}s")
+                    if (manager is not None and loop.ckpt_every
+                            and step and step % loop.ckpt_every == 0):
+                        manager.save(step, {"params": params,
+                                            "opt": opt_state,
+                                            "step": step_arr},
+                                     blocking=not loop.async_ckpt)
         finally:
             data.stop()
             if manager is not None:
                 manager.wait()
-        wall = time.perf_counter() - t_begin
         if manager is not None:
             manager.save(step, {"params": params, "opt": opt_state,
                                 "step": jnp.asarray(step, jnp.int32)},
                          blocking=True)
-        tokens = loop.global_batch * loop.seq_len * max(len(losses), 1)
+        # the steps after the first, over the time they took: the first
+        # step's compile is not throughput
+        timed = len(losses) - 1
+        tokens_per_s = (loop.global_batch * loop.seq_len * timed
+                        / (t_last - t_first) if timed > 0 else float("nan"))
         result = {"losses": losses, "grad_norms": grad_norms,
                   "step_seconds": step_seconds,
                   "plan": plan, "params": params, "opt_state": opt_state,
-                  "last_step": step, "wall_s": wall,
-                  "tokens_per_s": tokens / max(wall, 1e-9),
+                  "last_step": step, "tokens_per_s": tokens_per_s,
                   "straggler_events": len(watchdog.events)}
         if tracer is not None and tracer.spans:
             if loop.trace_path:
@@ -256,13 +295,15 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                 log_fn(f"[obs] wrote {len(tracer.spans)} spans to "
                        f"{loop.trace_path}")
             if offload_plan is not None:
-                # drift vs the last (warmest) step's per-op spans — earlier
-                # steps carry one-time jit/transfer warm-up costs
+                # drift vs the last (warmest) step's schedule ops — earlier
+                # steps carry one-time jit/transfer warm-up costs, and the
+                # loop's own train.* spans are no schedule op
                 from ..obs.drift import compare
                 from ..obs.trace import Tracer as _Tracer
-                n_ops = len(offload_plan.schedule)
+                kinds = {k for k, _ in offload_plan.schedule.ops}
+                ops = [s for s in tracer.spans if s.op in kinds]
                 last = _Tracer(name="train-last-step")
-                last.spans.extend(tracer.spans[-n_ops:])
+                last.spans.extend(ops[-len(offload_plan.schedule):])
                 report = compare(offload_plan, last)
                 log_fn(f"[obs] {report.summary()}")
                 result["drift"] = report
