@@ -1,16 +1,19 @@
-"""Blockwise (vocab-chunked) cross-entropy: never materializes the full
+"""Cross-entropy from hidden states that never materializes the full
 (B,S,V) logits tensor — the single largest activation for 150k-vocab models.
 
-Forward: a ``lax.scan`` over vocab blocks maintaining a running
-(max, sum-exp, gold-logit) triple; backward (custom VJP): a second scan
-recomputing each logits block and accumulating ``dh``/``dW`` — so peak
-memory is O(B·S·block) instead of O(B·S·V).  This is the paper's trade
-(recompute to bound memory) applied *inside* the loss stage, which the rotor
-profile consistently flags as the fattest ``ω_ā`` in the chain.
+``token_chunked_xent`` (the model's head): a ``lax.scan`` over token blocks,
+each against the whole (contiguous) vocabulary.  When the loss is
+differentiated, the same scan also computes the gradient: each block's
+logits are made once, and ``dh``/``dW`` come from them in that one pass, so
+the backward only scales the two by the incoming cotangent.  The loss is the
+chain's last stage and its cotangent a scalar, so nothing the backward needs
+is unknown in the forward.  Peak memory is O(block·V).
 
-A direct Pallas realization of the same loop is in this package's
-``kernel.py`` sibling modules' style, but the XLA scan already achieves the
-memory bound; the kernel variant was not needed to hit it (see DESIGN.md).
+``blockwise_xent`` (vocab-chunked): a ``lax.scan`` over vocab blocks
+maintaining a running (max, sum-exp, gold-logit) triple; its backward
+(custom VJP) is a second scan recomputing each logits block and
+accumulating ``dh``/``dW`` — so peak memory is O(B·S·block) instead of
+O(B·S·V).
 """
 
 from __future__ import annotations
@@ -124,14 +127,10 @@ def _bwd(block, z_loss, res, g):
 blockwise_xent.defvjp(_fwd, _bwd)
 
 
-def token_chunked_xent(h: jax.Array, w: jax.Array, labels: jax.Array,
-                       mask=None, block: int = 4096, z_loss: float = 0.0
-                       ) -> jax.Array:
-    """Token-block-chunked xent: scan over token blocks with a checkpointed
-    body, so only O(block × V) logits are ever live and the backward
-    rematerializes per block.  Unlike the vocab-chunked variant this keeps
-    the vocab dim contiguous, so under GSPMD the per-block matmul stays
-    TP-sharded on the model axis (vocab-chunking would serialize TP)."""
+def _token_blocks(h, labels, mask, block):
+    """Token-major blocks of ``h`` (B,S,d), ``labels`` and the loss mask
+    (ones where ``mask`` is None), the last padded with masked-out rows:
+    ``(hb (nb,block,d), lb (nb,block), mb (nb,block))``."""
     B, S, d = h.shape
     T = B * S
     h2 = h.reshape(T, d)
@@ -145,23 +144,83 @@ def token_chunked_xent(h: jax.Array, w: jax.Array, labels: jax.Array,
         lab = jnp.pad(lab, (0, pad))
         m1 = jnp.pad(m1, (0, pad))
     nb = h2.shape[0] // block
-    hb = h2.reshape(nb, block, d)
-    lb = lab.reshape(nb, block)
-    mb = m1.reshape(nb, block)
+    return (h2.reshape(nb, block, d), lab.reshape(nb, block),
+            m1.reshape(nb, block))
 
-    @jax.checkpoint
+
+def _block_xent(hblk, w, lblk, z_loss):
+    """One token block against the whole vocabulary: its f32 logits, their
+    logsumexp and the per-token loss."""
+    lo = hblk @ w.astype(hblk.dtype)
+    logits = lo.astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    # the gold logit is gathered before the (exact) cast: gathering from the
+    # f32 copy makes XLA write that whole (block, V) f32 array to HBM
+    gold = jnp.take_along_axis(lo, lblk[:, None], axis=-1)[:, 0].astype(
+        jnp.float32)
+    per = lse - gold
+    if z_loss:
+        per = per + z_loss * lse ** 2
+    return logits, lse, per
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def token_chunked_xent(h: jax.Array, w: jax.Array, labels: jax.Array,
+                       mask=None, block: int = 4096, z_loss: float = 0.0
+                       ) -> jax.Array:
+    """Token-block-chunked xent: a scan over token blocks, so only
+    O(block × V) logits are ever live.  Unlike the vocab-chunked variant
+    this keeps the vocab dim contiguous, so under GSPMD the per-block matmul
+    stays TP-sharded on the model axis (vocab-chunking would serialize TP).
+
+    Undifferentiated, each block costs one logits matmul.  Differentiated
+    (custom VJP), the forward scan also emits ``dh`` and accumulates ``dW``
+    from the same logits, in ``h``'s and ``w``'s dtypes; the backward scales
+    them by the cotangent and never recomputes a logits block."""
+    hb, lb, mb = _token_blocks(h, labels, mask, block)
+
     def body(carry, inp):
         lsum, msum = carry
         hblk, lblk, mblk = inp
-        logits = (hblk @ w.astype(hblk.dtype)).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lblk[:, None], axis=-1)[:, 0]
-        per = lse - gold
-        if z_loss:
-            per = per + z_loss * lse ** 2
+        _, _, per = _block_xent(hblk, w, lblk, z_loss)
         return (lsum + jnp.sum(per * mblk), msum + jnp.sum(mblk)), None
 
     (lsum, msum), _ = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
         (hb, lb, mb))
     return lsum / jnp.maximum(msum, 1.0)
+
+
+def _token_chunked_fwd(h, w, labels, mask, block, z_loss):
+    hb, lb, mb = _token_blocks(h, labels, mask, block)
+    denom = jnp.maximum(jnp.sum(mb), 1.0)
+    V = w.shape[1]
+
+    def body(carry, inp):
+        lsum, dw = carry
+        hblk, lblk, mblk = inp
+        logits, lse, per = _block_xent(hblk, w, lblk, z_loss)
+        p = jnp.exp(logits - lse[:, None])
+        if z_loss:
+            p = p * (1.0 + 2.0 * z_loss * lse)[:, None]
+        hit = lblk[:, None] == jnp.arange(V)[None, :]
+        dlogits = ((mblk / denom)[:, None]
+                   * (p - hit.astype(jnp.float32))).astype(hblk.dtype)
+        dh_blk = dlogits @ w.astype(hblk.dtype).T
+        dw = dw + (hblk.T @ dlogits).astype(w.dtype)
+        return (lsum + jnp.sum(per * mblk), dw), dh_blk
+
+    (lsum, dw), dhb = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), jnp.zeros_like(w)), (hb, lb, mb))
+    B, S, d = h.shape
+    dh = dhb.reshape(-1, d)[:B * S].reshape(B, S, d)
+    return lsum / denom, (dh, dw)
+
+
+def _token_chunked_bwd(block, z_loss, res, g):
+    dh, dw = res
+    # None: zero cotangents for labels (float0) and mask, with no residual
+    return ((dh * g).astype(dh.dtype), (dw * g).astype(dw.dtype), None, None)
+
+
+token_chunked_xent.defvjp(_token_chunked_fwd, _token_chunked_bwd)

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Tuple
 
 from ..core.devices import V5E
 
@@ -83,6 +83,18 @@ def collective_bytes_from_hlo(hlo_text: str) -> Dict[str, float]:
         out[kind] += float(operand)
     out["total"] = sum(v for k, v in out.items() if k != "total")
     return out
+
+
+_DOT_RE = re.compile(
+    r"=\s*[a-z0-9]+\[([0-9,]*)\][^ ]*\s+(?:dot|convolution)\(")
+
+
+def dot_shapes_from_hlo(hlo_text: str) -> List[Tuple[int, ...]]:
+    """Result shape of every ``dot``/``convolution`` instruction in the
+    compiled HLO text, fused computations included (a loop body's matmul
+    counts once, however many times the loop runs)."""
+    return [tuple(int(d) for d in m.group(1).split(",") if d)
+            for m in _DOT_RE.finditer(hlo_text)]
 
 
 @dataclasses.dataclass

@@ -22,6 +22,7 @@ from ..core.rematerialize import count_checkpoint_scopes
 from ..data.pipeline import SyntheticLMData
 from ..distributed.fault_tolerance import StragglerWatchdog
 from ..distributed.sharding import DEFAULT_RULES, axis_rules
+from ..launch.roofline import dot_shapes_from_hlo
 from ..launch.steps import (batch_axes, make_train_step, opt_axes,
                             plan_training, shard_tree, sharding_of)
 from ..models.lm import StagedLM
@@ -41,6 +42,16 @@ def _record_step_bytes(compiled) -> None:
     obs_metrics.gauge("train.step_bytes").set(
         ma.argument_size_in_bytes + ma.output_size_in_bytes
         - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+
+
+def _record_vocab_dots(compiled, vocab_size: int) -> None:
+    """Set ``train.vocab_dots``: the matmuls in the compiled step's HLO whose
+    result has a vocabulary-sized dimension (the head's logits and weight
+    gradient, and any recompute of them)."""
+    text = compiled.as_text()
+    if text:
+        obs_metrics.gauge("train.vocab_dots").set(sum(
+            vocab_size in shape for shape in dot_shapes_from_hlo(text)))
 
 
 def _make_offload_step(model, opt_cfg: AdamWConfig, schedule, lr_fn,
@@ -109,7 +120,8 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
     lowered and compiled once, on the first batch, inside ``train.compile``
     (the jit's own calls then find that executable); the compiled step's
     per-device bytes (arguments + outputs − aliased + temporaries) are the
-    gauge ``train.step_bytes``.
+    gauge ``train.step_bytes``, and its matmuls with a vocabulary-sized
+    result the gauge ``train.vocab_dots``.
 
     ``tracer`` (a :class:`repro.obs.trace.Tracer`, opt-in) records those
     spans too, and per-op spans on the eager offload path; when the offload
@@ -232,6 +244,7 @@ def run_training(cfg, loop: TrainLoopConfig, mesh=None,
                             compiled = step_fn.lower(params, opt_state, batch,
                                                      step_arr).compile()
                         _record_step_bytes(compiled)
+                        _record_vocab_dots(compiled, cfg.vocab_size)
                         compile_first = False
                     t_step = time.perf_counter()
                     with span("train.dispatch", tracer, step=step):

@@ -211,22 +211,98 @@ def test_vocab_blockwise_xent(V, block):
                                    rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("block", [4, 16, 64])
-def test_token_chunked_xent(block):
+def _xent_case(dtype=jnp.float32, masked=True):
     B, S, d, V = 2, 10, 16, 301
     key = jax.random.PRNGKey(13)
-    h = jax.random.normal(key, (B, S, d))
-    w = jax.random.normal(jax.random.fold_in(key, 1), (d, V)) * 0.1
+    h = jax.random.normal(key, (B, S, d)).astype(dtype)
+    w = (jax.random.normal(jax.random.fold_in(key, 1), (d, V)) * 0.1
+         ).astype(dtype)
     labels = jax.random.randint(jax.random.fold_in(key, 2), (B, S), 0, V)
     mask = (jax.random.uniform(jax.random.fold_in(key, 3), (B, S)) > 0.2
-            ).astype(jnp.float32)
-    l1 = xops.token_chunked_xent(h, w, labels, mask, block=block)
-    l2 = xref.xent_from_hidden(h, w, labels, mask)
+            ).astype(jnp.float32) if masked else None
+    return h, w, labels, mask
+
+
+# (block, mask, z_loss, dtype); T = 20 tokens, so blocks 7 and 16 pad the
+# last block, and 64 is cut to one block of 20
+@pytest.mark.parametrize("block, masked, z_loss, dtype", [
+    (4, True, 0.0, jnp.float32),
+    (16, True, 0.0, jnp.float32),
+    (64, True, 0.0, jnp.float32),
+    (4, False, 0.0, jnp.float32),
+    (4, True, 1e-3, jnp.float32),
+    (7, True, 0.0, jnp.float32),
+    (7, True, 1e-3, jnp.bfloat16),
+], ids=["4", "16", "64", "no_mask", "z_loss", "padded", "bf16"])
+def test_token_chunked_xent(block, masked, z_loss, dtype):
+    """Loss and both gradients against the full-logits oracle.  The custom
+    VJP makes (dh, dW) in the forward scan; in bf16 they are rounded to
+    bf16 per block, so they may sit a few bf16 roundings of the largest
+    entry away from the oracle's."""
+    h, w, labels, mask = _xent_case(dtype, masked)
+    l1 = xops.token_chunked_xent(h, w, labels, mask, block=block,
+                                 z_loss=z_loss)
+    l2 = xref.xent_from_hidden(h, w, labels, mask, z_loss)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
     g1 = jax.grad(lambda h_, w_: xops.token_chunked_xent(
-        h_, w_, labels, mask, block), argnums=(0, 1))(h, w)
+        h_, w_, labels, mask, block, z_loss), argnums=(0, 1))(h, w)
     g2 = jax.grad(lambda h_, w_: xref.xent_from_hidden(
-        h_, w_, labels, mask), argnums=(0, 1))(h, w)
+        h_, w_, labels, mask, z_loss), argnums=(0, 1))(h, w)
     for a, b in zip(g1, g2):
+        assert a.dtype == b.dtype == dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+        else:
+            np.testing.assert_allclose(
+                a, b, rtol=0, atol=4 * 2.0 ** -8 * np.abs(b).max())
+
+
+def _dots(fn, *args):
+    from repro.launch.roofline import dot_shapes_from_hlo
+
+    return dot_shapes_from_hlo(jax.jit(fn).lower(*args).compile().as_text())
+
+
+@pytest.mark.parametrize("masked", [True, False], ids=["mask", "no_mask"])
+def test_token_chunked_xent_makes_each_logits_block_once(masked):
+    """Differentiated, the compiled scan holds three matmuls (logits, dh,
+    dW) and no replay of the logits; undifferentiated, one."""
+    h, w, labels, mask = _xent_case(masked=masked)
+
+    def loss(h_, w_):
+        return xops.token_chunked_xent(h_, w_, labels, mask, 8)
+
+    assert len(_dots(jax.value_and_grad(loss, argnums=(0, 1)), h, w)) == 3
+    assert len(_dots(loss, h, w)) == 1
+
+
+def test_staged_lm_head_is_never_replayed_under_full_remat():
+    """A tiny ``StagedLM`` with a token-chunked head: under
+    ``full_remat_tree`` its gradients are store-all's, and the compiled
+    gradient holds two matmuls with a vocabulary-sized result (the logits
+    and dW), as the head is never inside a checkpoint."""
+    from repro.core.rematerialize import full_remat_tree
+    from repro.models.lm import ModelConfig, StagedLM
+
+    cfg = ModelConfig(name="tiny", num_layers=2, n_chunks=2, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=384,
+                      qkv_bias=True, mlp_kind="swiglu",
+                      scan_layer_remat="full", logits_chunk=16)
+    model = StagedLM(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(1)
+    B, S = 2, 24
+    batch = {"tokens": jax.random.randint(key, (B, S), 0, cfg.vocab_size),
+             "labels": jax.random.randint(jax.random.fold_in(key, 1), (B, S),
+                                          0, cfg.vocab_size),
+             "loss_mask": jnp.ones((B, S), jnp.float32)}
+    tree = full_remat_tree(model.n_stages() - 1)
+    g_store = jax.jit(jax.grad(lambda p: model.loss_fn(p, batch)))(params)
+    remat = jax.grad(lambda p: model.loss_fn(p, batch, tree=tree))
+    g_remat = jax.jit(remat)(params)
+    for a, b in zip(jax.tree.leaves(g_remat), jax.tree.leaves(g_store)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-4, atol=1e-5)
+                                   rtol=1e-5, atol=1e-7)
+    vocab = [s for s in _dots(remat, params) if cfg.vocab_size in s]
+    assert len(vocab) == 2, vocab

@@ -174,8 +174,11 @@ def test_gauges_after_a_tiny_run(monkeypatch):
     assert metrics.value("plan.predicted_step_s") == plan.expected_time
     assert metrics.value("plan.chain_s") > 0
     assert metrics.value("plan.solve_s") > 0
+    # the head's logits and dW, each made once (no replay of the logits)
+    assert metrics.value("train.vocab_dots") == 2
     for name in ("plan.chain_s", "plan.solve_s", "plan.predicted_step_s",
-                 "plan.planned_bytes", "train.step_bytes"):
+                 "plan.planned_bytes", "train.step_bytes",
+                 "train.vocab_dots"):
         assert metrics.registry().get(name).updates == 1, name
     # throughput of the steps after the first, over the time from the end
     # of the first to the end of the last: no faster than their own step
