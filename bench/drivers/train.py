@@ -22,14 +22,30 @@ the run loudly.
 
 from __future__ import annotations
 
+import importlib
 import math
 import sys
 import time
+from pathlib import Path
 
 import numpy as np
 
 #: ``run_training`` runs until the window closes; this only bounds it.
 MAX_STEPS = 1_000_000
+REFERENCES = Path(__file__).resolve().parent.parent / "reference"
+
+
+def reference_module(config: dict):
+    """The plain reference of a configuration: the module
+    ``bench/reference/<name>.py`` that its ``"reference"`` key names
+    (``"dense"`` by default), with ``Reference``, ``follow``, ``lr_at`` and
+    ``train_flops_per_token``."""
+    name = config.get("reference", "dense")
+    path = REFERENCES / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"the configuration's reference {name!r} "
+                                f"has no file {path}")
+    return importlib.import_module(f"bench.reference.{name}")
 
 
 class _WindowClosed(Exception):
@@ -120,11 +136,10 @@ def run(ctx) -> dict:
 
     from repro.runtime.train_loop import TrainLoopConfig, run_training
 
-    from bench import flops
     from bench.reference import compare
     from bench.reference.data import batch_at
-    from bench.reference.dense import Reference, follow, lr_at
 
+    reference = reference_module(ctx.config)
     t = ctx.traffic
     opt = ctx.config["optimizer"]
     check_steps, warm = int(t["check_steps"]), int(t["warm_steps"])
@@ -160,12 +175,13 @@ def run(ctx) -> dict:
     # the check: the reference follows the first steps from the same seed,
     # once the program's state is gone
     t_ref = time.perf_counter()
-    ref = Reference(ctx.model)
+    ref = reference.Reference(ctx.model)
     batches = [batch_at(ctx.model["vocab_size"], B, S, ctx.prog_seed, k)
                for k in range(check_steps)]
-    lrs = [lr_at(k, opt["lr"], opt["warmup"], MAX_STEPS)
+    lrs = [reference.lr_at(k, opt["lr"], opt["warmup"], MAX_STEPS)
            for k in range(check_steps)]
-    r = follow(ref, opt, jax.random.PRNGKey(ctx.prog_seed), batches, lrs)
+    r = reference.follow(ref, opt, jax.random.PRNGKey(ctx.prog_seed), batches,
+                         lrs, devices=ctx.devices)
     if r["paths"] != rec.mu_paths:
         raise RuntimeError("the program's parameter tree no longer matches "
                            "the reference's")
@@ -186,7 +202,7 @@ def run(ctx) -> dict:
               f"ref {float(r['delta'][i])!r}", file=sys.stderr, flush=True)
     checks = {k: {"value": nums[k], "limit": ctx.limits[k]}
               for k in ("loss", "grad", "update")}
-    fpt = flops.train_flops_per_token(ctx.model, S)
+    fpt = reference.train_flops_per_token(ctx.model, S)
     return {
         "e2e": {"train_tokens_per_s": tokens / window_s,
                 "setup_s": rec.setup_s},

@@ -13,9 +13,12 @@ the reference taking any array the program made.
 The gradient is computed stage by stage (embedding, each chunk of layers,
 final norm and head) and one row of the batch at a time, with the
 attention's query blocks and the head's token blocks recomputed in the
-backward pass, so that a step at the cells' sizes fits one chip beside
-float32 parameters and gradients.  Adam's moments live on the host and are
-updated one leaf at a time on the device.
+backward pass, so that a step fits beside float32 parameters and
+gradients.  That state lies on the cell's chips, each leaf split over them
+on its largest dimension that their count divides (``Trainer.sharding``),
+so that a model larger than one chip is checked at its own size.  Adam's
+moments live on the host and are updated one leaf at a time on the
+devices.
 
 ``quant="fp8"`` builds the control: every matrix product takes operands
 (and, in the backward pass, cotangents) rounded to float8 e4m3 with one
@@ -31,6 +34,9 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
+
+from bench.flops import train_flops_per_token  # noqa: F401  (re-exported)
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -296,12 +302,19 @@ class Trainer:
     """Follows the program's first training steps in float32: loss and
     gradient of each step, then AdamW as the configuration states it
     (global-norm clipping, bias correction, decoupled decay of matrices;
-    parameters stored back in the configuration's dtype)."""
+    parameters stored back in the configuration's dtype).
 
-    def __init__(self, ref: Reference, opt: dict):
+    The float32 parameters and gradient accumulators lie on ``devices``
+    (default: the first device) in the layout of :meth:`sharding`, and
+    every jitted piece keeps its outputs there."""
+
+    def __init__(self, ref: Reference, opt: dict, devices=None):
         self.ref = ref
         self.opt = opt
+        self.mesh = Mesh(np.asarray(devices or jax.devices()[:1]), ("state",),
+                         axis_types=(AxisType.Auto,))
         r = ref
+        keep = self.keep
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def head_bwd(hp, gacc, h, labels, mask, inv_denom):
@@ -309,25 +322,25 @@ class Trainer:
                 return r.head_loss_sum(hp, h, labels, mask) * inv_denom
             loss, vjp = jax.vjp(f, hp, h)
             ghp, dh = vjp(jnp.ones((), F32))
-            return loss, dh, jax.tree.map(jnp.add, gacc, ghp)
+            return loss, dh, keep(jax.tree.map(jnp.add, gacc, ghp))
 
         @functools.partial(jax.jit, donate_argnums=(1,))
         def chunk_bwd(cp, gacc, h, dh):
             _, vjp = jax.vjp(r.chunk, cp, h)
             gcp, dh_in = vjp(dh)
-            return dh_in, jax.tree.map(jnp.add, gacc, gcp)
+            return dh_in, keep(jax.tree.map(jnp.add, gacc, gcp))
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def embed_bwd(gacc, tokens, dh):
-            return {"table": gacc["table"].at[tokens].add(dh)}
+            return keep({"table": gacc["table"].at[tokens].add(dh)})
 
         self._head_bwd = head_bwd
         self._chunk_bwd = chunk_bwd
         self._embed_bwd = embed_bwd
         self._chunk_fwd = jax.jit(r.chunk)
         self._embed_fwd = jax.jit(r.embed)
-        self._zeros = jax.jit(lambda p: jax.tree.map(
-            lambda x: jnp.zeros(x.shape, F32), p))
+        self._zeros = jax.jit(lambda p: keep(jax.tree.map(
+            lambda x: jnp.zeros(x.shape, F32), p)))
         self._sq = jax.jit(lambda t: [jnp.sum(jnp.square(x.astype(F32)))
                                       for x in jax.tree.leaves(t)])
 
@@ -344,9 +357,40 @@ class Trainer:
             if decay:
                 step = step + wd * p
             # stored in the configuration's parameter dtype
-            return _round_to(p - lr * step, pdt), m, v
+            return keep((_round_to(p - lr * step, pdt), m, v))
 
         self._adam_leaf = adam_leaf
+
+    def sharding(self, shape) -> NamedSharding:
+        """Where a state leaf of ``shape`` lies: split over the devices on
+        its largest dimension that their count divides (the first of
+        equals), replicated where none does."""
+        n = self.mesh.size
+        spec = [None] * len(shape)
+        dims = [i for i, s in enumerate(shape) if s % n == 0]
+        if dims:
+            spec[max(dims, key=lambda i: shape[i])] = "state"
+        return NamedSharding(self.mesh, PartitionSpec(*spec))
+
+    def keep(self, tree):
+        """``tree`` held to the state's layout (inside ``jit``)."""
+        return jax.tree.map(lambda x: jax.lax.with_sharding_constraint(
+            x, self.sharding(x.shape)), tree)
+
+    def put(self, tree):
+        """``tree`` (host or device arrays) placed in the state's layout."""
+        return jax.tree.map(lambda x: jax.device_put(
+            x, self.sharding(x.shape)), tree)
+
+    def init_state(self, seed_key) -> tuple:
+        """The initial parameters from the seed: in the configuration's
+        dtype on the host (numpy), and in float32 on the devices."""
+        init = self.ref.init
+        shapes = jax.eval_shape(init, seed_key)
+        p0 = jax.jit(init, out_shardings=jax.tree.map(
+            lambda x: self.sharding(x.shape), shapes))(seed_key)
+        params = jax.tree.map(lambda x: x.astype(F32), p0)
+        return jax.device_get(p0), params
 
     def loss_and_grad(self, params, batch) -> tuple:
         """Mean loss and float32 gradient tree of one full batch.
@@ -396,7 +440,7 @@ class Trainer:
         for i, (p, g) in enumerate(zip(flat_p, flat_g)):
             m, v = moments[i]
             decay = bool(opt["weight_decay"]) and _is_matrix(paths[i], p.ndim)
-            p2, m2, v2 = self._adam_leaf(p, g, jnp.asarray(m), jnp.asarray(v),
+            p2, m2, v2 = self._adam_leaf(p, g, *self.put((m, v)),
                                          jnp.asarray(lr, F32), sc, decay)
             moments[i] = (np.asarray(m2), np.asarray(v2))
             out.append(p2)
@@ -425,17 +469,18 @@ def lr_at(step: int, base: float, warmup: int, total: int,
 
 
 def follow(ref: Reference, opt: dict, seed_key, batches: List[dict],
-           lrs: List[float]) -> Dict[str, Any]:
-    """Run the reference through ``len(batches)`` steps from the seed.
+           lrs: List[float], devices=None) -> Dict[str, Any]:
+    """Run the reference through ``len(batches)`` steps from the seed, its
+    state over ``devices`` (default: the first device).
 
     Returns the loss of each step, the per-leaf norms of the first
     gradient (before clipping), and the per-leaf norms of the parameters'
-    change after the last step, with the leaf paths."""
-    tr = Trainer(ref, opt)
-    p0 = jax.jit(ref.init)(seed_key)
+    change after the last step, with the leaf paths, and the initial
+    parameters (``init``, on the host)."""
+    tr = Trainer(ref, opt, devices)
+    p0, params = tr.init_state(seed_key)
     paths = [jax.tree_util.keystr(k) for k, _ in
              jax.tree_util.tree_flatten_with_path(p0)[0]]
-    params = jax.tree.map(lambda x: x.astype(F32), p0)
     moments = [(np.zeros(x.shape, np.float32), np.zeros(x.shape, np.float32))
                for x in jax.tree.leaves(p0)]
     losses, g0 = [], None
@@ -448,7 +493,7 @@ def follow(ref: Reference, opt: dict, seed_key, batches: List[dict],
         del grads
     delta = jax.jit(lambda a, b: [jnp.sqrt(jnp.sum(jnp.square(
         x.astype(F32) - y.astype(F32)))) for x, y in zip(
-            jax.tree.leaves(a), jax.tree.leaves(b))])(params, p0)
+            jax.tree.leaves(a), jax.tree.leaves(b))])(params, tr.put(p0))
     return {"losses": losses, "grad0": g0, "paths": paths,
             "delta": np.asarray(jax.device_get(delta), np.float64),
             "init": p0}

@@ -3,9 +3,11 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Everything
-about it is found by name: the configuration's file (``configs``), the
+about it is found by name: the configuration's file (``configs``, whose
+``reference`` names its plain reference ``bench/reference/<name>.py``), the
 traffic mix ``bench/traffic/<traffic>.json`` (whose ``kind`` names the
-driver ``bench/drivers/<kind>.py``), the limits of its correctness check
+driver ``bench/drivers/<kind>.py``, and whose ``mesh`` lays the cell's
+chips out as ``[data, model]``), the limits of its correctness check
 ``bench/limits/<workload>.json``, and each per-layer metric's reader
 ``bench/metrics/<metric>.py``.  A new cell, configuration, mix or metric
 is new files and new entries; no file here changes.
@@ -171,10 +173,16 @@ class Context:
         return model_config(self.model)
 
     def mesh(self):
+        """The cell's ``data`` x ``model`` mesh: the traffic file's
+        ``"mesh": [data, model]``, else every chip on ``data``."""
         from repro.launch.mesh import make_mesh
 
-        return make_mesh((len(self.devices), 1), ("data", "model"),
-                         devices=self.devices)
+        chips = self.cell["chips"]
+        shape = tuple(self.traffic.get("mesh", (chips, 1)))
+        if len(shape) != 2 or shape[0] * shape[1] != chips:
+            raise ValueError(f"the traffic's mesh {list(shape)} is not "
+                             f"[data, model] over the cell's {chips} chips")
+        return make_mesh(shape, ("data", "model"), devices=self.devices)
 
     def memory_peak(self) -> int:
         """Peak bytes in use on the fullest chip, as the allocator reports
